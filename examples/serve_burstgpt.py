@@ -24,9 +24,10 @@ def build_cluster(variant: str, n_engines: int = 2) -> Cluster:
     # stats into the same tracker and applies the same placements
     from repro.core.gimbal import make_cluster_expert_level
     level = make_cluster_expert_level(variant, cfg, n_engines, gcfg)
+    # replicas behind the router serve ONE model: one weight key for all
+    params = M.init_params(jax.random.key(0), cfg)
     engines = []
     for i in range(n_engines):
-        params = M.init_params(jax.random.key(i), cfg)
         engines.append(Engine(i, cfg, params, variant=variant, gimbal_cfg=gcfg,
                               max_slots=4, max_seq=128, prefill_budget=128,
                               expert_level=level))

@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import List, Optional
 
 # Priority classes, ordered most- to least-urgent.  Rank 0 (interactive)
 # may preempt rank 1 (batch) when GimbalConfig.enable_preemption is set.
@@ -48,6 +48,9 @@ class Request:
     kv_migrated: bool = False        # KV pages travelled with the re-route:
     #                                  progress survives, no re-prefill charge
     reroutes: int = 0                # times re-dispatched off a failed/removed engine
+    # greedy token ids emitted so far (live plane only; the first comes from
+    # prefill).  None until a JaxBackend starts the request.
+    output_tokens: Optional[List[int]] = None
 
     @property
     def rank(self) -> int:

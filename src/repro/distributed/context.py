@@ -13,20 +13,7 @@ import dataclasses
 import threading
 from typing import Optional, Tuple
 
-import jax
 from jax.sharding import Mesh, PartitionSpec as P
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: the top-level `jax.shard_map` (with
-    `check_vma`) only exists on newer releases; older ones ship it as
-    `jax.experimental.shard_map.shard_map` with the kwarg named `check_rep`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,14 +1,22 @@
-"""Flash-decode: single-token attention with online softmax over KV blocks
-streamed HBM -> VMEM (DESIGN.md §6).
+"""Flash-decode: single-token GQA attention with an online softmax over KV
+pages streamed HBM -> VMEM (DESIGN.md §6).
 
-Grid (B, Hkv, S/BS); the S axis is the sequential ("arbitrary") grid dim, so
-the (m, l, acc) running statistics live in VMEM scratch and are carried across
-KV blocks — the kernel analogue of the shard_map flash-decode combine in
+Page layout is head-major, (P, Hkv, BS, D): one grid step reads the (BS, D)
+tile of one kv head of one page.  Those are the array's own last two dims, so
+the block obeys the TPU tiling rule for every page size and head dim (a
+(BS, 1, D) slice of a token-major (P, BS, Hkv, D) page does not).
+
+Grid (B, Hkv, NB); the NB axis is the sequential ("arbitrary") grid dim, so
+the (m, l, acc) running statistics live in VMEM scratch and are carried
+across pages — the kernel analogue of the shard_map flash-decode combine in
 models/attention.py (which splits the same recurrence across chips).
 
 GQA-aware: the q block holds all G = Hq/Hkv query heads of one KV head, so
 each KV tile is read exactly once per group (the roofline-optimal layout:
 decode attention is KV-bandwidth-bound).
+
+``flash_decode`` (a contiguous (B, S, Hkv, D) cache) is the same kernel over
+that cache cut into private pages with an identity block table.
 """
 from __future__ import annotations
 
@@ -19,97 +27,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 NEG_INF = -2.0 ** 30
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-            *, block_s: int, softcap: float):
-    si = pl.program_id(2)
-    n_s = pl.num_programs(2)
-
-    @pl.when(si == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    length = len_ref[0]
-
-    # Skip fully-masked KV blocks entirely: no wasted flops past `length`, and
-    # a length-0 row leaves l at 0 so the output is exactly zero (with a finite
-    # NEG_INF mask an unguarded block would contribute exp(0)=1 everywhere and
-    # emit mean(v) instead).
-    @pl.when(si * block_s < length)
-    def _update():
-        q = q_ref[0, 0]                             # (G, D)
-        k = k_ref[0, :, 0, :]                       # (BS, D)
-        v = v_ref[0, :, 0, :]                       # (BS, D)
-
-        s = jnp.dot(q.astype(jnp.float32), k.astype(jnp.float32).T)  # (G, BS)
-        s = s * (q.shape[-1] ** -0.5)
-        if softcap > 0:
-            s = jnp.tanh(s / softcap) * softcap
-        jpos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(jpos < length, s, NEG_INF)
-
-        m_prev = m_ref[...]                          # (G, 1)
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        p = jnp.exp(s - m_new)                       # (G, BS)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(p, v.astype(jnp.float32))
-        m_ref[...] = m_new
-
-    @pl.when(si == n_s - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
-                       ).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("block_s", "softcap", "interpret"))
-def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, lengths: jax.Array,
-                 *, block_s: int = 256, softcap: float = 0.0,
-                 interpret: bool = False) -> jax.Array:
-    """q: (B, Hq, D); k, v: (B, S, Hkv, D); lengths: (B,).  -> (B, Hq, D)."""
-    b, hq, d = q.shape
-    s, hkv = k.shape[1], k.shape[2]
-    g = hq // hkv
-    bs = min(block_s, s)
-    sp = -(-s // bs) * bs
-    if sp != s:
-        k = jnp.pad(k, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
-    qg = q.reshape(b, hkv, g, d)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, block_s=bs, softcap=softcap),
-        grid=(b, hkv, sp // bs),
-        in_specs=[
-            pl.BlockSpec((1,), lambda bi, hi, si: (bi,)),
-            pl.BlockSpec((1, 1, g, d), lambda bi, hi, si: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d), lambda bi, hi, si: (bi, si, hi, 0)),
-            pl.BlockSpec((1, bs, 1, d), lambda bi, hi, si: (bi, si, hi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda bi, hi, si: (bi, hi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),         # m
-            pltpu.VMEM((g, 1), jnp.float32),         # l
-            pltpu.VMEM((g, d), jnp.float32),         # acc
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(lengths, qg, k, v)
-    return out.reshape(b, hq, d)
-
-
-def _paged_kernel(bt_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
-                  o_ref, m_ref, l_ref, acc_ref,
-                  *, block_s: int, softcap: float, quantized: bool):
+def _kernel(bt_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
+            o_ref, m_ref, l_ref, acc_ref,
+            *, block_s: int, softcap: float, quantized: bool):
     bi = pl.program_id(0)
     si = pl.program_id(2)
     n_s = pl.num_programs(2)
@@ -122,17 +45,22 @@ def _paged_kernel(bt_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
 
     length = len_ref[bi]
 
+    # Skip fully-masked pages entirely: no wasted flops past `length`, and a
+    # length-0 row leaves l at 0 so the output is exactly zero (with a finite
+    # NEG_INF mask an unguarded page would contribute exp(0)=1 everywhere and
+    # emit mean(v) instead).
     @pl.when(si * block_s < length)
     def _update():
-        q = q_ref[0, 0]                             # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)   # (BS, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)         # (G, D)
+        k = k_ref[0, 0].astype(jnp.float32)         # (BS, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         if quantized:
             blk = bt_ref[bi, si]                    # physical page id
             k = k * ks_ref[blk]
             v = v * vs_ref[blk]
 
-        s = jnp.dot(q.astype(jnp.float32), k.T)     # (G, BS)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # (G, BS)
         s = s * (q.shape[-1] ** -0.5)
         if softcap > 0:
             s = jnp.tanh(s / softcap) * softcap
@@ -144,7 +72,8 @@ def _paged_kernel(bt_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
         p = jnp.exp(s - m_new)                       # (G, BS)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(p, v)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(si == n_s - 1)
@@ -161,18 +90,20 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                        interpret: bool = False) -> jax.Array:
     """Block-table-indexed flash decode over a paged KV pool.
 
-    q: (B, Hq, D); k_pages, v_pages: (P, BS, Hkv, D) global page pool;
+    q: (B, Hq, D); k_pages, v_pages: (P, Hkv, BS, D) global page pool;
     block_tables: (B, NB) int32 physical page per logical block; lengths: (B,)
     valid tokens per row.  Optional per-page int8 scales (P,) f32 dequantize
     pages in-kernel.  Returns (B, Hq, D).
 
-    The block table and lengths ride in as scalar-prefetch operands
-    (pltpu.PrefetchScalarGridSpec), so the k/v BlockSpec index maps select the
-    PHYSICAL page for grid step (b, h, si) — the standard TPU paged-attention
-    trick: the DMA engine chases the indirection, not the compute loop.
-    Fully-masked pages are skipped (pl.when on `si*BS < length`)."""
+    The block table, lengths and scales ride in as scalar-prefetch operands
+    (pltpu.PrefetchScalarGridSpec, SMEM), so the k/v BlockSpec index maps
+    select the PHYSICAL page for grid step (b, h, si) — the standard TPU
+    paged-attention trick: the DMA engine chases the indirection, not the
+    compute loop.  Fully-masked pages are skipped (pl.when on
+    `si*BS < length`).  ``interpret`` runs the kernel in the Pallas
+    interpreter (CPU); it has no default other than compiled."""
     b, hq, d = q.shape
-    _, bs, hkv, _ = k_pages.shape
+    _, hkv, bs, _ = k_pages.shape
     nb = block_tables.shape[1]
     g = hq // hkv
     qg = q.reshape(b, hkv, g, d)
@@ -184,15 +115,15 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         return (bi, hi, 0, 0)
 
     def kv_map(bi, hi, si, bt, ln, ks_, vs_):
-        return (bt[bi, si], 0, hi, 0)
+        return (bt[bi, si], hi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b, hkv, nb),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), q_map),
-            pl.BlockSpec((1, bs, 1, d), kv_map),
-            pl.BlockSpec((1, bs, 1, d), kv_map),
+            pl.BlockSpec((1, 1, bs, d), kv_map),
+            pl.BlockSpec((1, 1, bs, d), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), q_map),
         scratch_shapes=[
@@ -202,12 +133,37 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, block_s=bs, softcap=softcap,
+        functools.partial(_kernel, block_s=bs, softcap=softcap,
                           quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables, lengths, ks, vs, qg, k_pages, v_pages)
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), ks, vs,
+      qg, k_pages, v_pages)
     return out.reshape(b, hq, d)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_s", "softcap", "interpret"))
+def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, lengths: jax.Array,
+                 *, block_s: int = 256, softcap: float = 0.0,
+                 interpret: bool = False) -> jax.Array:
+    """q: (B, Hq, D); k, v: (B, S, Hkv, D); lengths: (B,).  -> (B, Hq, D).
+
+    Each row's S axis is cut into NB = ceil(S / BS) private pages (BS =
+    min(block_s, S)) and handed to ``flash_decode_paged`` with the identity
+    block table."""
+    b, s, hkv, d = k.shape
+    bs = min(block_s, s)
+    nb = -(-s // bs)
+
+    def pages(x):
+        x = jnp.pad(x, ((0, 0), (0, nb * bs - s), (0, 0), (0, 0)))
+        return x.reshape(b, nb, bs, hkv, d).transpose(0, 1, 3, 2, 4
+                                                      ).reshape(b * nb, hkv, bs, d)
+
+    tables = jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
+    return flash_decode_paged(q, pages(k), pages(v), tables, lengths,
+                              softcap=softcap, interpret=interpret)

@@ -18,8 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
+import jax.experimental.pallas.tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, o_ref):
@@ -54,7 +53,7 @@ def moe_gemm(xe: jax.Array, w: jax.Array, *, block_c: int = 128,
         ],
         out_specs=pl.BlockSpec((1, bc, bf), lambda ei, ci, fi: (ei, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((e, cp, fp), xe.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(xe, w)

@@ -48,21 +48,21 @@ def ref_flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                            k_scale: jax.Array = None,
                            v_scale: jax.Array = None) -> jax.Array:
     """Paged single-token GQA decode attention (block-table indexed).
-    q: (B, Hq, D); k_pages, v_pages: (P, BS, Hkv, D) global page pool;
-    block_tables: (B, NB) int32 physical page per logical block (page 0 is the
-    reserved garbage page); lengths: (B,) valid KV length.  Optional
+    q: (B, Hq, D); k_pages, v_pages: (P, Hkv, BS, D) head-major global page
+    pool; block_tables: (B, NB) int32 physical page per logical block (page 0
+    is the reserved garbage page); lengths: (B,) valid KV length.  Optional
     per-page int8 scales k_scale/v_scale: (P,) f32.  Returns (B, Hq, D)."""
     b = q.shape[0]
-    p_, bs, hkv, d = k_pages.shape
+    p_, hkv, bs, d = k_pages.shape
     nb = block_tables.shape[1]
-    k = k_pages[block_tables].astype(jnp.float32)     # (B, NB, BS, Hkv, D)
+    k = k_pages[block_tables].astype(jnp.float32)     # (B, NB, Hkv, BS, D)
     v = v_pages[block_tables].astype(jnp.float32)
     if k_scale is not None:
         k = k * k_scale[block_tables][:, :, None, None, None]
     if v_scale is not None:
         v = v * v_scale[block_tables][:, :, None, None, None]
-    k = k.reshape(b, nb * bs, hkv, d)
-    v = v.reshape(b, nb * bs, hkv, d)
+    k = k.transpose(0, 1, 3, 2, 4).reshape(b, nb * bs, hkv, d)
+    v = v.transpose(0, 1, 3, 2, 4).reshape(b, nb * bs, hkv, d)
     return ref_flash_decode(q, k, v, lengths, softcap)
 
 

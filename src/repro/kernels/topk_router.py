@@ -2,13 +2,23 @@
 
 The per-token scheduling primitive the Gimbal expert level feeds on: gates and
 expert ids drive dispatch; the position-in-expert counter implements the
-GShard capacity rule.  Cross-token positions need a running per-expert counter
+GShard capacity rule.  Cross-token positions need a running per-slot counter
 -> the token-block grid axis is sequential ("arbitrary") and the counter lives
 in VMEM scratch, carried across blocks (same pattern as flash_decode's online
 softmax state).
 
-Top-k is computed by iterative argmax (k <= 8 for every assigned arch), which
-vectorizes on the VPU without sorting networks.
+Top-k is computed by iterative max (k <= 8 for every assigned arch): the
+selected index is the lowest lane holding the row max, which is lax.top_k's
+tie order.  Everything stays 2-D (tokens on sublanes, experts on lanes) and
+every per-selection column is written with a lane select, so the kernel needs
+no reshape, stack or cumsum — none of which Mosaic lowers for these shapes.
+
+Capacity positions: the k selections of one token hit k distinct slots (each
+slot holds exactly one logical expert), so the position of selection (t, j)
+in slot s is the number of EARLIER tokens of the stream that selected s.
+Within a block that is a strictly-lower-triangular (BT, BT) matmul against
+the (BT, S) 0/1 slot-occupancy matrix; exact in f32 accumulation.  VMEM use
+is O(BT·S + BT²) whatever the token count.
 
 Replicated placements (hot-expert redundancy, core/placement.py) are handled
 in-kernel by ``topk_router_replicated``: logical expert ids are mapped to one
@@ -25,8 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -2.0 ** 30
 
@@ -45,56 +53,62 @@ def _kernel(x_ref, rs_ref, rc_ref, gates_ref, ids_ref, slots_ref, pos_ref,
     p = jnp.exp(logits - m)
     probs = p / p.sum(-1, keepdims=True)
 
+    iota = jax.lax.broadcasted_iota
+    lane_e = iota(jnp.int32, (bt, e), 1).astype(jnp.float32)
+    lane_k = iota(jnp.int32, (bt, k), 1)
+    lane_s = iota(jnp.int32, (bt, num_slots), 1)
+
     work = probs
-    gsel = []
-    isel = []
-    for _ in range(k):                               # iterative argmax top-k
-        idx = jnp.argmax(work, axis=-1)              # (BT,)
-        val = jnp.max(work, axis=-1)
-        gsel.append(val)
-        isel.append(idx)
-        onehot = jax.lax.broadcasted_iota(jnp.int32, work.shape, 1) == idx[:, None]
-        work = jnp.where(onehot, NEG_INF, work)
-    gates = jnp.stack(gsel, axis=-1)                 # (BT, k)
-    ids = jnp.stack(isel, axis=-1).astype(jnp.int32)
+    gates = jnp.zeros((bt, k), jnp.float32)
+    ids = jnp.zeros((bt, k), jnp.int32)
+    slots = jnp.zeros((bt, k), jnp.int32)
+    occ = jnp.zeros((bt, num_slots), jnp.float32)    # token -> slot, 0/1
+    sel_slots = []
+    for j in range(k):                               # iterative-max top-k
+        val = work.max(-1, keepdims=True)            # (BT, 1)
+        idx_f = jnp.min(jnp.where(work == val, lane_e, float(e)), -1,
+                        keepdims=True)               # lowest lane at the max
+        hit = lane_e == idx_f                        # (BT, E) one-hot
+        work = jnp.where(hit, NEG_INF, work)
+        idx = idx_f.astype(jnp.int32)
+        if replicated:
+            # slot = replica_slots[e, (t*k + j) % replica_count[e]] via
+            # one-hot lane selects over the tiny (max_rep, E) table
+            cnt = jnp.sum(jnp.where(hit, rc_ref[...], 0.0), -1,
+                          keepdims=True).astype(jnp.int32)
+            row = iota(jnp.int32, (bt, 1), 0)
+            sel = (ti * bt + row) * k + j            # global selection index
+            r = jax.lax.rem(sel, jnp.maximum(cnt, 1))
+            slot = idx
+            for c in range(rs_ref.shape[0]):
+                cand = jnp.sum(jnp.where(hit, rs_ref[c:c + 1, :], 0.0), -1,
+                               keepdims=True).astype(jnp.int32)
+                slot = jnp.where(r == c, cand, slot)
+        else:
+            slot = idx
+        gates = jnp.where(lane_k == j, val, gates)
+        ids = jnp.where(lane_k == j, idx, ids)
+        slots = jnp.where(lane_k == j, slot, slots)
+        occ = occ + (lane_s == slot).astype(jnp.float32)
+        sel_slots.append(slot)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
-    flat_ids = ids.reshape(-1)                       # (BT*k,) logical
-    if replicated:
-        # slot = replica_slots[e, (t*k + j) % replica_count[e]] — one-hot
-        # selects (no gathers; the tables are tiny and live in VMEM)
-        max_rep = rs_ref.shape[1]
-        oh_e = (jax.lax.broadcasted_iota(jnp.int32, (bt * k, e), 1)
-                == flat_ids[:, None]).astype(jnp.float32)         # (BT*k, E)
-        cnt = (oh_e * rc_ref[...].astype(jnp.float32)
-               ).sum(-1).astype(jnp.int32)                        # (BT*k,)
-        sel = (jax.lax.broadcasted_iota(jnp.int32, (bt, k), 0) * k
-               + jax.lax.broadcasted_iota(jnp.int32, (bt, k), 1)
-               + ti * bt * k).reshape(-1)            # global selection index
-        r = sel % jnp.maximum(cnt, 1)
-        # one-hot matmul contraction over E (NOT a 3D broadcast, whose
-        # (BT*k, E, max_rep) intermediate would blow past VMEM at real
-        # shapes); slot ids are small ints, exact in f32
-        rows = oh_e @ rs_ref[...].astype(jnp.float32)    # (BT*k, max_rep)
-        oh_r = (jax.lax.broadcasted_iota(jnp.int32, (bt * k, max_rep), 1)
-                == r[:, None]).astype(jnp.float32)
-        slot_flat = (rows * oh_r).sum(-1).astype(jnp.int32)
-    else:
-        slot_flat = flat_ids
-
     # capacity positions: token-major then selection order (GShard rule),
-    # counted per PHYSICAL slot
-    sel_oh = (jax.lax.broadcasted_iota(jnp.int32, (bt * k, num_slots), 1)
-              == slot_flat[:, None]).astype(jnp.int32)   # (BT*k, S)
-    run = jnp.cumsum(sel_oh, axis=0) - 1             # 0-based within block
+    # counted per PHYSICAL slot and carried across blocks in count_ref
+    earlier = (iota(jnp.int32, (bt, bt), 1)
+               < iota(jnp.int32, (bt, bt), 0)).astype(jnp.float32)
     base = count_ref[...]                            # (1, S) carried counter
-    pos_flat = ((run + base) * sel_oh).sum(-1)       # (BT*k,)
-    count_ref[...] = base + sel_oh.sum(0, keepdims=True)
+    run = jnp.dot(earlier, occ, preferred_element_type=jnp.float32) + base
+    pos = jnp.zeros((bt, k), jnp.int32)
+    for j, slot in enumerate(sel_slots):
+        pj = jnp.sum(jnp.where(lane_s == slot, run, 0.0), -1, keepdims=True)
+        pos = jnp.where(lane_k == j, pj.astype(jnp.int32), pos)
+    count_ref[...] = base + occ.sum(0, keepdims=True)
 
     gates_ref[...] = gates
     ids_ref[...] = ids
-    slots_ref[...] = slot_flat.reshape(bt, k).astype(jnp.int32)
-    pos_ref[...] = pos_flat.reshape(bt, k).astype(jnp.int32)
+    slots_ref[...] = slots
+    pos_ref[...] = pos
 
 
 def _call(logits: jax.Array, k: int, replica_slots, replica_count,
@@ -103,7 +117,8 @@ def _call(logits: jax.Array, k: int, replica_slots, replica_count,
     bt = min(block_t, t)
     tp = -(-t // bt) * bt
     if tp != t:
-        # pad rows route to expert argmax of zeros=0 but are sliced off below
+        # pad rows come after every real token (so they never shift a real
+        # capacity position) and are sliced off below
         logits = jnp.pad(logits, ((0, tp - t), (0, 0)),
                          constant_values=NEG_INF / 2)
     replicated = replica_slots is not None
@@ -111,13 +126,16 @@ def _call(logits: jax.Array, k: int, replica_slots, replica_count,
         replica_slots = jnp.arange(e, dtype=jnp.int32)[:, None]
         replica_count = jnp.ones((e,), jnp.int32)
     max_rep = replica_slots.shape[1]
+    # slot ids and counts are small ints, exact in f32
+    rs_t = jnp.asarray(replica_slots, jnp.float32).T            # (max_rep, E)
+    rc = jnp.asarray(replica_count, jnp.float32).reshape(1, e)
     gates, ids, slots, pos = pl.pallas_call(
         functools.partial(_kernel, k=k, num_slots=num_slots,
                           replicated=replicated),
         grid=(tp // bt,),
         in_specs=[
             pl.BlockSpec((bt, e), lambda ti: (ti, 0)),
-            pl.BlockSpec((e, max_rep), lambda ti: (0, 0)),
+            pl.BlockSpec((max_rep, e), lambda ti: (0, 0)),
             pl.BlockSpec((1, e), lambda ti: (0, 0)),
         ],
         out_specs=[
@@ -132,17 +150,16 @@ def _call(logits: jax.Array, k: int, replica_slots, replica_count,
             jax.ShapeDtypeStruct((tp, k), jnp.int32),
             jax.ShapeDtypeStruct((tp, k), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, num_slots), jnp.int32)],
-        compiler_params=_CompilerParams(
+        scratch_shapes=[pltpu.VMEM((1, num_slots), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(logits, jnp.asarray(replica_slots, jnp.int32),
-      jnp.asarray(replica_count, jnp.int32).reshape(1, e))
+    )(logits.astype(jnp.float32), rs_t, rc)
     return gates[:t], ids[:t], slots[:t], pos[:t]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_t", "interpret"))
-def topk_router(logits: jax.Array, k: int, *, block_t: int = 1024,
+def topk_router(logits: jax.Array, k: int, *, block_t: int = 256,
                 interpret: bool = False):
     """logits: (T, E).  Returns (gates (T,k) f32, ids (T,k) i32, pos (T,k) i32)."""
     t, e = logits.shape
@@ -154,7 +171,7 @@ def topk_router(logits: jax.Array, k: int, *, block_t: int = 1024,
                    static_argnames=("k", "num_slots", "block_t", "interpret"))
 def topk_router_replicated(logits: jax.Array, k: int,
                            replica_slots: jax.Array, replica_count: jax.Array,
-                           num_slots: int, *, block_t: int = 1024,
+                           num_slots: int, *, block_t: int = 256,
                            interpret: bool = False):
     """Replica-aware router.  replica_slots: (E, max_rep) physical slots per
     logical expert (padded with the primary); replica_count: (E,);

@@ -6,6 +6,14 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # Auto axes: the model code places arrays with with_sharding_constraint,
+    # which rejects the Explicit axes jax.make_mesh defaults to
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,12 +25,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh for tests / small-scale functional runs."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return _mesh(shape, axes)
 
 
 def batch_axes_of(mesh) -> tuple:

@@ -1,42 +1,103 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Functional-plane cluster (real jax engines on reduced configs) serving a
-BurstGPT- or ShareGPT-shaped trace with the full Gimbal stack, health
-monitoring, and optional fault injection — the deployment-shaped entry point
-(dryrun.py proves the same step functions lower on the production mesh).
+A cluster of real JAX engines serving a BurstGPT- or ShareGPT-shaped trace
+with the full Gimbal stack, health monitoring, and optional fault injection —
+the deployment-shaped entry point.
+
+Every engine serves through the paged KV pool (16-token pages) with the
+fused MoE dispatch and the Pallas kernels on the decode path; the kernels are
+compiled on a TPU and interpreted elsewhere (``JaxBackend.kernel_mode``).
+``--size smoke`` (the default) serves the arch's reduced CPU config;
+``--size full --depth N`` serves its published widths cut to N layers (the
+depth is required: each engine holds all its weights on one device).
+Replica *i* serves from ``jax.devices()[i]`` (round-robin when there are
+fewer devices than engines), and every replica holds the same weights.
+
+The run exits non-zero when any request is still unfinished at ``HORIZON``.
 """
 from __future__ import annotations
 
 import argparse
 import copy
+import os
+import sys
+from pathlib import Path
+from typing import Optional, Sequence
 
 import jax
 
-from repro.configs import get_smoke_config, list_archs
+from repro.configs import at_depth, get_config, get_smoke_config, list_archs
 from repro.core.types import GimbalConfig
 from repro.distributed.fault import HealthConfig, HealthMonitor
 from repro.models import model as M
+from repro.models.config import ModelConfig
 from repro.serving.cluster import Cluster
 from repro.serving.engine import Engine
 from repro.workloads.burstgpt import burstgpt_trace
 from repro.workloads.sharegpt import sharegpt_trace
 
+REPO_ROOT = Path(__file__).resolve().parents[3]
+HORIZON = 120.0        # logical seconds served before unfinished requests fail
 
-def build_cluster(arch: str, variant: str, n_engines: int,
-                  gcfg: GimbalConfig) -> Cluster:
-    cfg = get_smoke_config(arch)
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory:
+    ``$JAX_COMPILATION_CACHE_DIR`` where set (JAX reads it itself), else the
+    fixed ``.jax_cache/`` of this checkout."""
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not d:
+        d = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", d)
+    return d
+
+
+def model_config(arch: str, size: str = "smoke",
+                 depth: Optional[int] = None) -> ModelConfig:
+    """The served config: the reduced smoke variant, or the published widths
+    (``size="full"``), optionally cut to ``depth`` layers."""
+    if size == "smoke":
+        return get_smoke_config(arch)
+    cfg = get_config(arch)
+    return at_depth(cfg, depth) if depth else cfg
+
+
+def init_params(cfg: ModelConfig, seed: int = 0,
+                device: Optional[jax.Device] = None):
+    """Random weights from ``seed``, generated by one compiled program
+    straight onto ``device`` (no per-layer host-side copies)."""
+    device = device if device is not None else jax.devices()[0]
+    return jax.jit(M.init_params, static_argnums=1,
+                   out_shardings=jax.sharding.SingleDeviceSharding(device))(
+        jax.random.key(seed), cfg)
+
+
+def build_cluster(cfg: ModelConfig, variant: str, n_engines: int,
+                  gcfg: GimbalConfig, *, max_slots: int = 4,
+                  max_seq: int = 128, prefill_budget: int = 128,
+                  devices: Optional[Sequence] = None) -> Cluster:
+    """``n_engines`` replicas of one model behind the router.  Engine *i*
+    serves from ``devices[i % len(devices)]`` (default ``jax.devices()``)
+    with the same weights (one key)."""
+    devices = list(devices) if devices is not None else jax.devices()
+    params = init_params(cfg, device=devices[0])
     engines = []
     for i in range(n_engines):
-        params = M.init_params(jax.random.key(i), cfg)
-        engines.append(Engine(i, cfg, params, variant=variant, gimbal_cfg=gcfg,
-                              max_slots=4, max_seq=128, prefill_budget=128,
-                              num_expert_devices=max(2, min(4, cfg.num_experts or 2))))
+        engines.append(Engine(
+            i, cfg, params, variant=variant, gimbal_cfg=gcfg,
+            max_slots=max_slots, max_seq=max_seq,
+            prefill_budget=prefill_budget,
+            num_expert_devices=max(2, min(4, cfg.num_experts or 2)),
+            kv_layout="paged", kv_block_size=16, dispatch_mode="fused",
+            use_kernels=True, device=devices[i % len(devices)]))
     return Cluster(engines, variant=variant, gimbal_cfg=gcfg)
 
 
-def main():
+def main(argv: Optional[Sequence[str]] = None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-30b-a3b", choices=list_archs())
+    ap.add_argument("--size", default="smoke", choices=["smoke", "full"])
+    ap.add_argument("--depth", type=int, default=None,
+                    help="layers kept of the full config (needed by --size full)")
     ap.add_argument("--variant", default="gimbal",
                     choices=["vllm", "dplb", "sjfs", "edr", "gimbal"])
     ap.add_argument("--engines", type=int, default=2)
@@ -45,10 +106,14 @@ def main():
     ap.add_argument("--rps", type=float, default=20.0)
     ap.add_argument("--fail-engine", type=int, default=-1,
                     help="inject a failure of this engine mid-run")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    if args.size == "full" and not args.depth:
+        ap.error("--size full needs --depth")
 
+    use_compile_cache()
     gcfg = GimbalConfig(tau=25, theta_load=64)
-    cluster = build_cluster(args.arch, args.variant, args.engines, gcfg)
+    cfg = model_config(args.arch, args.size, args.depth)
+    cluster = build_cluster(cfg, args.variant, args.engines, gcfg)
     monitor = HealthMonitor(list(cluster.engines), HealthConfig())
 
     if args.trace == "burstgpt":
@@ -66,7 +131,7 @@ def main():
     trace = [copy.copy(r) for r in trace]
     i, now, dt = 0, 0.0, 0.05
     failed_at = None
-    while True:
+    while now <= HORIZON:
         while i < len(trace) and trace[i].arrival_time <= now:
             cluster.submit(trace[i], now)
             i += 1
@@ -82,22 +147,25 @@ def main():
             print(f"[serve] re-routed {moved} requests")
             failed_at = now
         now += dt
-        if i >= len(trace) and all(
-                e.num_active() == 0 and len(e.queue) == 0
-                for e in cluster.engines.values() if e.healthy):
-            break
-        if now > 120.0:
+        if i >= len(trace) and cluster.drained():
             break
 
     rep = cluster.report()
     pf = cluster.prefix_stats()
     relocs = sum(e.relocations for e in cluster.engines.values())
-    print(f"[serve] {args.variant} on {args.arch}: {rep.n}/{len(trace)} done | "
+    print(f"[serve] {args.variant} on {cfg.name} ({args.size}, "
+          f"{cfg.num_layers} layers): {rep.n}/{len(trace)} done | "
           f"TTFT mean {rep.mean_ttft:.3f}s p99 {rep.p99_ttft:.3f}s | "
           f"TPOT {rep.mean_tpot*1e3:.1f}ms | {rep.throughput_tok_s:.0f} tok/s")
     print(f"[serve] prefix hits {pf['hit_blocks']} "
           f"(rate {100*pf['hit_rate']:.1f}%) | expert relocations {relocs}")
+    unfinished = len(trace) - i + cluster.pending()
+    if unfinished:
+        print(f"[serve] ERROR: {unfinished} requests unfinished at the "
+              f"{HORIZON:g} s horizon", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.distributed.context import current_ctx, divides, shard_map_compat
+from repro.distributed.context import current_ctx, divides
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_rope, rms_norm, softcap
 
@@ -105,7 +105,10 @@ def _sdpa(cfg: ModelConfig, q, k, v, mask) -> jax.Array:
     b, sq, hq, d = q.shape
     k = _expand_kv(k, hq)
     v = _expand_kv(v, hq)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * (d ** -0.5)
+    # scores leave the MXU in f32 (no bf16 rounding before the softmax), as
+    # kernels/flash_decode.py computes them
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * (d ** -0.5)
     if cfg.attn_logit_softcap > 0:
         scores = softcap(scores, cfg.attn_logit_softcap)
     scores = jnp.where(mask[:, None, :, :], scores, NEG_INF)
@@ -142,7 +145,8 @@ def _sdpa_chunked(cfg: ModelConfig, q, k, v, window: int, causal: bool = True,
 
     def one(ci):
         qb = jax.lax.dynamic_slice_in_dim(q, ci * qc, qc, axis=1)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", qb, k).astype(jnp.float32) * scale
+        scores = jnp.einsum("bqhd,bkhd->bhqk", qb, k,
+                            preferred_element_type=jnp.float32) * scale
         if cfg.attn_logit_softcap > 0:
             scores = softcap(scores, cfg.attn_logit_softcap)
         i = (ci * qc + jnp.arange(qc))[:, None] + (skv - sq)
@@ -242,13 +246,13 @@ def gqa_decode(params: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
 
 def _paged_append_int8(pages, scales, phys, off, new):
     """Append one token per row into int8 pages with per-page scales.
-    pages: (P, BS, Hkv, D) int8; scales: (P,) f32; phys/off: (B,) page id /
+    pages: (P, Hkv, BS, D) int8; scales: (P,) f32; phys/off: (B,) page id /
     in-page offset; new: (B, Hkv, D) f32.  The scale update is MONOTONE
     (never shrinks), so when the new token fits the old scale the requantize
     round-trips existing entries exactly (round(q*s/s) == q)."""
     blk = pages[phys].astype(jnp.float32) * scales[phys][:, None, None, None]
     blk = jax.vmap(
-        lambda c, t, o: jax.lax.dynamic_update_slice(c, t[None], (o, 0, 0))
+        lambda c, t, o: jax.lax.dynamic_update_slice(c, t[:, None], (0, o, 0))
     )(blk, new.astype(jnp.float32), off)
     amax = jnp.max(jnp.abs(blk), axis=(1, 2, 3))
     new_scale = jnp.maximum(scales[phys], jnp.maximum(amax, 1e-12) / 127.0)
@@ -259,10 +263,10 @@ def _paged_append_int8(pages, scales, phys, off, new):
 
 def gqa_decode_paged(params: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
                      block_tables: jax.Array, lengths: jax.Array, local: bool,
-                     use_kernel: bool = False):
+                     use_kernel: bool = False, interpret: bool = False):
     """One-token decode against a paged KV pool (one layer's pages).
 
-    x: (B,1,d); cache: {"k": (P,BS,Hkv,D), "v": ..., optional "k_scale"/
+    x: (B,1,d); cache: {"k": (P,Hkv,BS,D), "v": ..., optional "k_scale"/
     "v_scale": (P,) f32 for int8 pages}; block_tables: (B,NB) physical page per
     logical block (page 0 = reserved garbage page — free rows write there);
     lengths: (B,) tokens resident = write position.  Returns (out, new_cache).
@@ -270,13 +274,16 @@ def gqa_decode_paged(params: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
     The host guarantees (PagedKVCache.prepare_append) that active rows' tail
     pages are private (copy-on-write) and allocated; inactive rows carry
     lengths=0 and all-zero table rows, so their scatter lands in the garbage
-    page and their (discarded) output attends only to it."""
+    page and their (discarded) output attends only to it.
+
+    ``use_kernel`` runs the attention through the Pallas flash_decode_paged
+    kernel, compiled unless ``interpret`` asks for the interpreter (CPU)."""
     q, k_new, v_new = _qkv(params, cfg, x)
     q = apply_rope(q, lengths[:, None], cfg.rope_theta)
     k_new = apply_rope(k_new, lengths[:, None], cfg.rope_theta)
 
     b = x.shape[0]
-    bs_blk = cache["k"].shape[1]
+    bs_blk = cache["k"].shape[2]
     nb = block_tables.shape[1]
     bidx = lengths // bs_blk
     off = lengths % bs_blk
@@ -290,31 +297,30 @@ def gqa_decode_paged(params: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
         new_cache["v"], new_cache["v_scale"] = _paged_append_int8(
             cache["v"], cache["v_scale"], phys, off, v_new[:, 0])
     else:
-        new_cache["k"] = cache["k"].at[phys, off].set(
+        new_cache["k"] = cache["k"].at[phys, :, off].set(
             k_new[:, 0].astype(cache["k"].dtype))
-        new_cache["v"] = cache["v"].at[phys, off].set(
+        new_cache["v"] = cache["v"].at[phys, :, off].set(
             v_new[:, 0].astype(cache["v"].dtype))
 
     windowed = local and cfg.sliding_window > 0
     if use_kernel and not windowed:
         from repro.kernels.flash_decode import flash_decode_paged
-        from repro.kernels.ops import auto_interpret
         o = flash_decode_paged(
             q[:, 0], new_cache["k"], new_cache["v"], block_tables, lengths + 1,
             k_scale=new_cache.get("k_scale"), v_scale=new_cache.get("v_scale"),
-            softcap=float(cfg.attn_logit_softcap),
-            interpret=auto_interpret(None))
+            softcap=float(cfg.attn_logit_softcap), interpret=interpret)
         out = o[:, None].astype(x.dtype)
     else:
-        kb = new_cache["k"][block_tables]                     # (B,NB,BS,Hkv,D)
+        kb = new_cache["k"][block_tables]                     # (B,NB,Hkv,BS,D)
         vb = new_cache["v"][block_tables]
         if quantized:
             kb = kb.astype(jnp.float32) \
                 * new_cache["k_scale"][block_tables][..., None, None, None]
             vb = vb.astype(jnp.float32) \
                 * new_cache["v_scale"][block_tables][..., None, None, None]
-        kb = kb.reshape(b, nb * bs_blk, cache["k"].shape[2], cache["k"].shape[3])
-        vb = vb.reshape(b, nb * bs_blk, cache["v"].shape[2], cache["v"].shape[3])
+        hkv, dh = cache["k"].shape[1], cache["k"].shape[3]
+        kb = kb.transpose(0, 1, 3, 2, 4).reshape(b, nb * bs_blk, hkv, dh)
+        vb = vb.transpose(0, 1, 3, 2, 4).reshape(b, nb * bs_blk, hkv, dh)
         j = jnp.arange(nb * bs_blk)[None, :]
         mask = j <= lengths[:, None]
         if windowed:
@@ -388,7 +394,7 @@ def _gqa_decode_seqsharded(cfg: ModelConfig, q, k_new, v_new, cache, cache_pos,
 
     rep4 = P(b_ax, None, None, None)
     shard4 = P(b_ax, ctx.model_axis, None, None)
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(rep4, rep4, rep4, shard4, shard4, P(b_ax)),
         out_specs=(rep4, shard4, shard4),
@@ -607,7 +613,7 @@ def _mla_decode_seqsharded(cfg: ModelConfig, params, q_nope, q_rope, ckv_new,
     rep3 = P(b_ax, None, None)
     rep4 = P(b_ax, None, None, None)
     shard3 = P(b_ax, ctx.model_axis, None)
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(rep4, rep4, rep4, rep3, rep3, shard3, shard3, P(b_ax),
                   P(None, None, None)),

@@ -18,14 +18,17 @@ from repro.models.config import ModelConfig
 from repro.models.layers import ffn_apply, init_ffn, init_rms_norm, rms_norm
 
 
-def _moe(p, cfg, h, placement, dispatch_mode, stats):
+def _moe(p, cfg, h, placement, dispatch_mode, stats, interpret):
     """Dispatch to the shard_map expert-parallel path when a shard context is
-    active (distributed lowering), else the single-device reference path."""
+    active (distributed lowering), else the single-device reference path.
+    ``interpret`` runs the "fused" dispatch's Pallas kernels in the
+    interpreter (CPU) instead of compiling them."""
     ctx = current_ctx()
     if ctx is not None and cfg.num_experts % ctx.tp == 0:
         from repro.models.moe_sharded import moe_apply_sharded
         return moe_apply_sharded(p, cfg, h, placement, ctx, stats)
-    return moe_lib.moe_apply(p, cfg, h, placement, dispatch_mode, stats)
+    return moe_lib.moe_apply(p, cfg, h, placement, dispatch_mode, stats,
+                             interpret=interpret)
 
 
 # --- init ---------------------------------------------------------------------
@@ -65,7 +68,8 @@ def init_cross_block(key, cfg: ModelConfig) -> dict:
 # --- apply: attention-family block ------------------------------------------------
 
 def attn_block_full(p: dict, cfg: ModelConfig, x, positions, is_local, cache,
-                    is_moe_layer: bool, placement, dispatch_mode: str, stats: bool):
+                    is_moe_layer: bool, placement, dispatch_mode: str, stats: bool,
+                    interpret: bool = False):
     h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps)
     if (cfg.sliding_window > 0 and cfg.local_global_period > 0
             and not isinstance(is_local, bool)):
@@ -86,7 +90,8 @@ def attn_block_full(p: dict, cfg: ModelConfig, x, positions, is_local, cache,
     h = rms_norm(x, p["ffn_norm"]["scale"], cfg.norm_eps)
     aux = {}
     if is_moe_layer:
-        y, aux = _moe(p["moe"], cfg, h, placement, dispatch_mode, stats)
+        y, aux = _moe(p["moe"], cfg, h, placement, dispatch_mode, stats,
+                      interpret)
     else:
         y = ffn_apply(p["ffn"], h)
     x = x + y
@@ -95,7 +100,7 @@ def attn_block_full(p: dict, cfg: ModelConfig, x, positions, is_local, cache,
 
 def attn_block_decode(p: dict, cfg: ModelConfig, x, cache, cache_pos, is_local,
                       is_moe_layer: bool, placement, dispatch_mode: str, stats: bool,
-                      mla_absorb: bool = False):
+                      mla_absorb: bool = False, interpret: bool = False):
     h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps)
     if (cfg.sliding_window > 0 and cfg.local_global_period > 0
             and not isinstance(is_local, bool)):
@@ -111,7 +116,8 @@ def attn_block_decode(p: dict, cfg: ModelConfig, x, cache, cache_pos, is_local,
     h = rms_norm(x, p["ffn_norm"]["scale"], cfg.norm_eps)
     aux = {}
     if is_moe_layer:
-        y, aux = _moe(p["moe"], cfg, h, placement, dispatch_mode, stats)
+        y, aux = _moe(p["moe"], cfg, h, placement, dispatch_mode, stats,
+                      interpret)
     else:
         y = ffn_apply(p["ffn"], h)
     x = x + y
@@ -121,7 +127,7 @@ def attn_block_decode(p: dict, cfg: ModelConfig, x, cache, cache_pos, is_local,
 def attn_block_decode_paged(p: dict, cfg: ModelConfig, x, cache, block_tables,
                             lengths, is_local, is_moe_layer: bool, placement,
                             dispatch_mode: str, stats: bool,
-                            use_kernel: bool = False):
+                            use_kernel: bool = False, interpret: bool = False):
     """attn_block_decode against one layer's paged KV pool (GQA only;
     PagedKVCache rejects other families up front)."""
     h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps)
@@ -129,10 +135,10 @@ def attn_block_decode_paged(p: dict, cfg: ModelConfig, x, cache, block_tables,
             and not isinstance(is_local, bool)):
         a_local, c_local = attn.gqa_decode_paged(p["attn"], cfg, h, cache,
                                                  block_tables, lengths, True,
-                                                 use_kernel)
+                                                 use_kernel, interpret)
         a_glob, c_glob = attn.gqa_decode_paged(p["attn"], cfg, h, cache,
                                                block_tables, lengths, False,
-                                               use_kernel)
+                                               use_kernel, interpret)
         a = jnp.where(is_local, a_local, a_glob)
         new_cache = jax.tree.map(lambda l, g: jnp.where(is_local, l, g),
                                  c_local, c_glob)
@@ -140,12 +146,13 @@ def attn_block_decode_paged(p: dict, cfg: ModelConfig, x, cache, block_tables,
         local = is_local if isinstance(is_local, bool) else False
         a, new_cache = attn.gqa_decode_paged(p["attn"], cfg, h, cache,
                                              block_tables, lengths, local,
-                                             use_kernel)
+                                             use_kernel, interpret)
     x = x + a
     h = rms_norm(x, p["ffn_norm"]["scale"], cfg.norm_eps)
     aux = {}
     if is_moe_layer:
-        y, aux = _moe(p["moe"], cfg, h, placement, dispatch_mode, stats)
+        y, aux = _moe(p["moe"], cfg, h, placement, dispatch_mode, stats,
+                      interpret)
     else:
         y = ffn_apply(p["ffn"], h)
     x = x + y

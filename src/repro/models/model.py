@@ -195,13 +195,13 @@ def _seq_constraint(x: jax.Array) -> jax.Array:
 
 def _scan_attn_stack(params, cfg: ModelConfig, x, positions, cache, cache_pos,
                      placements, dispatch_mode, stats, decode: bool,
-                     mla_absorb: bool = False):
+                     mla_absorb: bool = False, interpret: bool = False):
     """Scan over the attention-family stack (homogeneous, or interleaved-MoE
     super-blocks for moe_every > 1)."""
     if cfg.is_moe and cfg.moe_every > 1:
         return _scan_interleaved(params, cfg, x, positions, cache, cache_pos,
                                  placements, dispatch_mode, stats, decode,
-                                 mla_absorb)
+                                 mla_absorb, interpret)
     ctx = current_ctx()
     if (ctx is not None and ctx.paired_lg and cfg.local_global_period == 2
             and cfg.sliding_window > 0 and not cfg.is_moe
@@ -218,10 +218,11 @@ def _scan_attn_stack(params, cfg: ModelConfig, x, positions, cache, cache_pos,
                if inv is not None else None)
         if decode:
             x, newc, aux = B.attn_block_decode(p, cfg, x, c, cache_pos, flag, is_moe,
-                                               plc, dispatch_mode, stats, mla_absorb)
+                                               plc, dispatch_mode, stats, mla_absorb,
+                                               interpret)
         else:
             x, newc, aux = B.attn_block_full(p, cfg, x, positions, flag, c, is_moe,
-                                             plc, dispatch_mode, stats)
+                                             plc, dispatch_mode, stats, interpret)
         return _seq_constraint(x), (newc, aux)
 
     if cfg.remat:
@@ -272,7 +273,7 @@ def _scan_paired_local_global(params, cfg: ModelConfig, x, positions, cache,
 
 def _scan_interleaved(params, cfg: ModelConfig, x, positions, cache, cache_pos,
                       placements, dispatch_mode, stats, decode: bool,
-                      mla_absorb: bool = False):
+                      mla_absorb: bool = False, interpret: bool = False):
     """llama4-style interleaved MoE: scan over super-blocks of
     [1 MoE layer + (moe_every-1) dense layers]."""
     pstack = _placement_stack(cfg, placements)   # (n_super, S) or None
@@ -282,10 +283,10 @@ def _scan_interleaved(params, cfg: ModelConfig, x, positions, cache, cache_pos,
             return B.attn_block_decode(p, cfg, x, c, cache_pos, False,
                                        is_moe_layer, apply_block.plc,
                                        dispatch_mode, stats and is_moe_layer,
-                                       mla_absorb)
+                                       mla_absorb, interpret)
         return B.attn_block_full(p, cfg, x, positions, False, c, is_moe_layer,
                                  apply_block.plc, dispatch_mode,
-                                 stats and is_moe_layer)
+                                 stats and is_moe_layer, interpret)
 
     def super_body(x, xs):
         pm, pd, cm, cd, inv = xs
@@ -337,9 +338,10 @@ def forward(params, cfg: ModelConfig, tokens: Optional[jax.Array] = None, *,
             cache=None, cache_pos=None, decode: bool = False,
             vision_embeds=None, frames=None,
             placements=None, dispatch_mode: str = "dense", stats: bool = False,
-            mla_absorb: bool = False):
+            mla_absorb: bool = False, interpret: bool = False):
     """One entry point for train-forward (cache=None), prefill (cache given,
-    full seq) and decode (decode=True, one token).
+    full seq) and decode (decode=True, one token).  ``interpret`` runs the
+    Pallas kernels of dispatch_mode="fused" in the interpreter (CPU).
 
     Returns (logits, new_cache, aux).  logits: (B, S, V) fp32.
     """
@@ -383,15 +385,17 @@ def forward(params, cfg: ModelConfig, tokens: Optional[jax.Array] = None, *,
             if decode:
                 x, newc, _ = B.attn_block_decode(params["prologue"][i], cfg, x, c,
                                                  cache_pos, False, False, None,
-                                                 dispatch_mode, False, mla_absorb)
+                                                 dispatch_mode, False, mla_absorb,
+                                                 interpret)
             else:
                 x, newc, _ = B.attn_block_full(params["prologue"][i], cfg, x, positions,
-                                               False, c, False, None, dispatch_mode, False)
+                                               False, c, False, None, dispatch_mode, False,
+                                               interpret)
             pro_caches.append(newc)
         layer_cache = cache["layers"] if cache is not None else None
         x, new_layer_cache, auxs = _scan_attn_stack(
             params, cfg, x, positions, layer_cache, cache_pos,
-            placements, dispatch_mode, stats, decode, mla_absorb)
+            placements, dispatch_mode, stats, decode, mla_absorb, interpret)
         aux = _agg_aux(auxs)
         new_cache = None
         if cache is not None:
@@ -516,14 +520,18 @@ def decode_step(params, cfg: ModelConfig, token, cache, cache_pos, **kw):
 
 def decode_step_paged(params, cfg: ModelConfig, token, pages, block_tables,
                       lengths, *, placements=None, dispatch_mode: str = "dense",
-                      stats: bool = False, use_kernel: bool = False):
+                      stats: bool = False, use_kernel: bool = False,
+                      interpret: bool = False):
     """One decode step against a paged KV pool (serving/kvcache.PagedKVCache).
 
     token: (B, 1) int32; pages: per-layer page pytree with leading L
-    ({"k": (L,P,BS,Hkv,D), "v": ..., optional "k_scale"/"v_scale": (L,P)});
+    ({"k": (L,P,Hkv,BS,D), "v": ..., optional "k_scale"/"v_scale": (L,P)});
     block_tables: (B, NB) int32; lengths: (B,) tokens resident per row.
     Homogeneous GQA stacks only (no prologue / hybrid / MLA — PagedKVCache
-    enforces this at construction).  Returns (logits (B,V), new_pages, aux)."""
+    enforces this at construction).  ``use_kernel`` takes attention through
+    flash_decode_paged; ``interpret`` runs every Pallas kernel of the step in
+    the interpreter (CPU) instead of compiling it.  Returns (logits (B,V),
+    new_pages, aux)."""
     x = embed_apply(params["embed"], token)
     flags = local_flags(cfg)
     is_moe = cfg.is_moe
@@ -535,7 +543,7 @@ def decode_step_paged(params, cfg: ModelConfig, token, pages, block_tables,
                if inv is not None else None)
         x, newc, aux = B.attn_block_decode_paged(
             p, cfg, x, c, block_tables, lengths, flag, is_moe, plc,
-            dispatch_mode, stats, use_kernel)
+            dispatch_mode, stats, use_kernel, interpret)
         return _seq_constraint(x), (newc, aux)
 
     x, (new_pages, auxs) = jax.lax.scan(

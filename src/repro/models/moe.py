@@ -14,12 +14,15 @@ capacity budget, so replicating a hot expert can only RESCUE tokens the
 unreplicated placement would have dropped — fewer drops, never different
 routing for surviving tokens.
 
-Two dispatch strategies (same numerics; §Perf compares them):
+Three dispatch strategies (same numerics; §Perf compares them):
   * "dense"  — GShard/Switch-style one-hot einsum dispatch (classic TPU MoE,
                our paper-faithful baseline).
   * "gather" — sort-free gather/scatter dispatch: build an (E, C) token-index
                table with the same capacity rule, gather tokens, grouped GEMM,
                scatter-add back.  Avoids the O(T·E·C·d) dispatch matmuls.
+  * "fused"  — "gather" with the Pallas kernels: the replica-aware top-k
+               router (kernels/topk_router.py) and the grouped expert GEMM
+               (kernels/moe_gemm.py), compiled unless ``interpret`` is set.
 """
 from __future__ import annotations
 
@@ -146,6 +149,15 @@ def _expert_ffn(params: dict, xe: jax.Array) -> jax.Array:
     return jnp.einsum("ecf,efd->ecd", act, params["w_down"])
 
 
+def _expert_ffn_kernel(params: dict, xe: jax.Array, interpret: bool) -> jax.Array:
+    """_expert_ffn through three moe_gemm Pallas calls."""
+    from repro.kernels.moe_gemm import moe_gemm
+    gate = moe_gemm(xe, params["w_gate"], interpret=interpret)
+    up = moe_gemm(xe, params["w_up"], interpret=interpret)
+    act = jax.nn.silu(gate.astype(jnp.float32)).astype(xe.dtype) * up
+    return moe_gemm(act, params["w_down"], interpret=interpret)
+
+
 def _dispatch_tables(slot_idx: jax.Array, gates: jax.Array, num_slots: int, capacity: int):
     """Capacity assignment shared by both dispatch modes.
 
@@ -166,10 +178,11 @@ def _dispatch_tables(slot_idx: jax.Array, gates: jax.Array, num_slots: int, capa
 def moe_apply(params: dict, cfg: ModelConfig, x: jax.Array,
               placement: Optional[ExpertPlacement] = None,
               dispatch_mode: str = "dense",
-              return_stats: bool = False):
+              return_stats: bool = False, interpret: bool = False):
     """x: (B, S, d).  Returns (y, aux) where aux carries router losses and,
     when return_stats, per-expert activation counts + per-token expert ids
-    (the signals Gimbal's affinity/EPLB collectors consume)."""
+    (the signals Gimbal's affinity/EPLB collectors consume).  ``interpret``
+    runs the "fused" mode's kernels in the Pallas interpreter (CPU)."""
     b, s, d = x.shape
     t = b * s
     e, k = cfg.num_experts, cfg.moe_top_k
@@ -186,9 +199,10 @@ def moe_apply(params: dict, cfg: ModelConfig, x: jax.Array,
         # ids, physical slots AND per-slot capacity positions in one pass
         # (VMEM count scratch carried across token blocks) — same contract as
         # top_k_gating + dispatch_slots + _dispatch_tables.
-        from repro.kernels.ops import route_replicated_pallas
-        gates, expert_ids, slot_idx, pos = route_replicated_pallas(
-            logits, k, placement.replica_slots, placement.replica_count, ns)
+        from repro.kernels.topk_router import topk_router_replicated
+        gates, expert_ids, slot_idx, pos = topk_router_replicated(
+            logits, k, placement.replica_slots, placement.replica_count, ns,
+            interpret=interpret)
         keep = pos < cap
     else:
         gates, expert_ids = top_k_gating(probs, k)                 # (T,k) logical
@@ -217,16 +231,17 @@ def moe_apply(params: dict, cfg: ModelConfig, x: jax.Array,
         xe = jnp.where(valid[..., None],
                        jnp.take(xf, jnp.minimum(table, t - 1), axis=0), 0).astype(x.dtype)
         if dispatch_mode == "fused":
-            from repro.kernels.ops import expert_ffn_pallas
-            ye = expert_ffn_pallas(params, xe)                     # 3x moe_gemm
+            ye = _expert_ffn_kernel(params, xe, interpret)         # 3x moe_gemm
         else:
             ye = _expert_ffn(params, xe)
-        # combine: scatter-add expert outputs back, weighted by gate
+        # combine: scatter-add expert outputs back, weighted by gate.  The k
+        # weighted outputs of a token are summed in f32 and rounded once, as
+        # the dense einsum combine does: k bf16 roundings drift from it.
         gate_tbl = jnp.zeros((ns + 1, cap), x.dtype).at[slot_flat, pos_flat].set(
             (gates * keep).reshape(-1), mode="drop")[:ns]
-        y = jnp.zeros((t, d), x.dtype).at[jnp.minimum(table, t - 1).reshape(-1)].add(
-            (ye * gate_tbl[..., None]).reshape(ns * cap, d) *
-            valid.reshape(-1, 1).astype(x.dtype), mode="drop")
+        y = jnp.zeros((t, d), jnp.float32).at[jnp.minimum(table, t - 1).reshape(-1)].add(
+            (ye.astype(jnp.float32) * gate_tbl[..., None]).reshape(ns * cap, d) *
+            valid.reshape(-1, 1), mode="drop").astype(x.dtype)
     else:
         raise ValueError(f"unknown dispatch_mode {dispatch_mode!r}")
 

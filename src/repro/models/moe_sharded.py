@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.context import ShardCtx, divides, shard_map_compat
+from repro.distributed.context import ShardCtx, divides
 from repro.models.config import ModelConfig
 from repro.models.layers import ffn_apply
 from repro.models.moe import (ExpertPlacement, _capacity, _dispatch_tables,
@@ -214,7 +214,7 @@ def moe_apply_sharded(params: dict, cfg: ModelConfig, x: jax.Array,
     t_shard = (b // bdim if b_ax else b) * s
     fn = body_a2a if (ctx.ep_mode == "a2a" and not token_gather
                       and divides(t_shard, tp)) else body
-    y = shard_map_compat(
+    y = jax.shard_map(
         fn, mesh=ctx.mesh,
         in_specs=(P(b_ax, None, None), P(b_ax, None, None), P(b_ax, None, None),
                   wg_spec, wg_spec, wd_spec),

@@ -12,6 +12,7 @@ cluster/simulator owns the clock), so behaviour tests are deterministic.
 from __future__ import annotations
 
 import functools
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, List, Optional, Sequence, Set, Tuple
 
 import jax
@@ -34,8 +35,15 @@ def _bucket(n: int, lo: int = 16) -> int:
 
 class JaxBackend:
     """Backend protocol implementation over the real JAX model (runs the
-    actual compute; used with reduced configs on CPU, the same code path a
-    TPU deployment would jit).
+    actual compute on ``device``: its parameters, KV pool and every step's
+    inputs are placed there, so several backends in one process can each
+    serve from their own chip).
+
+    ``kernel_mode`` is decided once, from the platform of ``device``:
+    "compiled" on a TPU (Mosaic kernels), "interpret" elsewhere (the Pallas
+    interpreter — how the CPU tests run the kernel paths).  It only matters
+    when the step runs a kernel (``dispatch_mode="fused"`` or
+    ``use_kernels``).
 
     ``charge_prefix_hits`` is False: the live engine recomputes the full
     prefill (its prefix cache is a routing/affinity signal, not block reuse),
@@ -49,24 +57,30 @@ class JaxBackend:
                  eos_id: Optional[int] = None, dispatch_mode: str = "dense",
                  rebalancer: Optional[ExpertRebalancer] = None,
                  kv_layout: str = "slot", kv_block_size: int = 16,
-                 kv_quant: Optional[str] = None, use_kernels: bool = False):
+                 kv_quant: Optional[str] = None, use_kernels: bool = False,
+                 device: Optional[jax.Device] = None):
         assert kv_layout in ("slot", "paged")
         assert kv_quant in (None, "int8")
         self.cfg = model_cfg
-        self.params = params
+        self.device = device if device is not None else jax.devices()[0]
+        self.kernel_mode = ("compiled" if self.device.platform == "tpu"
+                            else "interpret")
+        self.params = jax.device_put(params, self.device)
         self.rebalancer = rebalancer
         self.kv_layout = kv_layout
         self.use_kernels = use_kernels
         if kv_layout == "paged":
             self.kv = PagedKVCache(model_cfg, max_slots, max_seq,
                                    block_size=kv_block_size,
-                                   quantize=(kv_quant == "int8"))
+                                   quantize=(kv_quant == "int8"),
+                                   device=self.device)
             # block-granular accounting: SchedulerCore rounds every per-request
             # charge up to whole blocks and gates admission on distinct blocks
             self.kv_block_size = kv_block_size
             kv_capacity = self.kv.capacity_tokens
         else:
-            self.kv = SlotKVCache(model_cfg, max_slots, max_seq)
+            self.kv = SlotKVCache(model_cfg, max_slots, max_seq,
+                                  device=self.device)
             self.kv_block_size = 1
             kv_capacity = max_slots * max_seq
         self.max_slots = max_slots
@@ -100,10 +114,14 @@ class JaxBackend:
             self._make_prefill)
 
     # ------------------------------------------------------------------ jit fns
+    def _put(self, x) -> jax.Array:
+        """Host array -> this backend's device."""
+        return jax.device_put(np.asarray(x), self.device)
+
     def _placements(self):
         if self.rebalancer is None:
             return None
-        return jnp.asarray(self.rebalancer.placement_stack(self._n_scan))
+        return self._put(self.rebalancer.placement_stack(self._n_scan))
 
     def _sync_placement(self) -> None:
         """Catch up with the (possibly cluster-shared) expert level: when
@@ -124,7 +142,8 @@ class JaxBackend:
         stats = self.cfg.is_moe and self.rebalancer is not None
         return M.decode_step(params, self.cfg, tokens, cache, cache_pos,
                              placements=placements, stats=stats,
-                             dispatch_mode=self.dispatch_mode)
+                             dispatch_mode=self.dispatch_mode,
+                             interpret=self.kernel_mode == "interpret")
 
     def _decode_paged_fn(self, params, tokens, pages, block_tables, lengths,
                          placements):
@@ -133,19 +152,48 @@ class JaxBackend:
                                    block_tables, lengths,
                                    placements=placements, stats=stats,
                                    dispatch_mode=self.dispatch_mode,
-                                   use_kernel=self.use_kernels)
+                                   use_kernel=self.use_kernels,
+                                   interpret=self.kernel_mode == "interpret")
 
     def _make_prefill(self, plen: int):
         @jax.jit
-        def fn(params, tokens, slot_cache, placements):
+        def fn(params, tokens, placements):
+            # the batch=1 cache is born inside the program, on the device
+            slot_cache = M.init_cache(self.cfg, 1, self.max_seq)
             return M.prefill(params, self.cfg, tokens, slot_cache,
                              placements=placements,
-                             dispatch_mode=self.dispatch_mode)
+                             dispatch_mode=self.dispatch_mode,
+                             interpret=self.kernel_mode == "interpret")
         return fn
 
     def prefill_cache_info(self):
         """(hits, misses, ...) of the bucketed prefill jit cache."""
         return self._prefill_for_bucket.cache_info()
+
+    def warmup(self, prompt_lens: Sequence[int]) -> None:
+        """Compile (or load from the compile cache) the prefill program of
+        each of these prompt lengths' buckets and the decode step, side by
+        side in threads, by running each once on dummy inputs.  Nothing is
+        written: the KV pool and the slots stay as they were."""
+        placements = self._placements()
+        buckets = sorted({_bucket(min(n, self.max_seq - 1))
+                          for n in prompt_lens})
+        jobs = [functools.partial(self._prefill_for_bucket(bl), self.params,
+                                  self._put(np.zeros((1, bl), np.int32)),
+                                  placements) for bl in buckets]
+        tokens = self._put(self.slot_last_token[:, None])
+        pos = self._put(self.kv.positions())
+        if self.kv_layout == "paged":
+            jobs.append(functools.partial(
+                self._jit_decode_paged, self.params, tokens, self.kv.pages,
+                self._put(self.kv.block_tables), pos, placements))
+        else:
+            jobs.append(functools.partial(
+                self._jit_decode, self.params, tokens, self.kv.cache, pos,
+                placements))
+        with ThreadPoolExecutor(len(jobs)) as pool:
+            outs = list(pool.map(lambda job: job(), jobs))
+        jax.block_until_ready(outs)
 
     # ------------------------------------------------------------------ Backend protocol
     def start(self, r: Request, now: float
@@ -167,12 +215,11 @@ class JaxBackend:
             slot = self.kv.alloc()
         assert slot is not None, "SchedulerCore admitted past slot capacity"
         bl = _bucket(plen)
-        padded = np.zeros(bl, np.int32)
-        padded[:plen] = toks
-        slot_cache = M.init_cache(self.cfg, 1, self.max_seq)
+        padded = np.zeros((1, bl), np.int32)
+        padded[0, :plen] = toks
         fn = self._prefill_for_bucket(bl)
-        logits, slot_cache, aux = fn(self.params, jnp.asarray(padded)[None],
-                                     slot_cache, self._placements())
+        logits, slot_cache, aux = fn(self.params, self._put(padded),
+                                     self._placements())
         if self.kv_layout == "paged":
             self.kv.write_prefill(slot, slot_cache)
         else:
@@ -180,7 +227,10 @@ class JaxBackend:
                                        self.kv.write_axes)
         self.slot_req[slot] = r
         self.kv.slot_len[slot] = plen
-        self.slot_last_token[slot] = int(jnp.argmax(logits[0, plen - 1]))
+        first = int(jnp.argmax(logits[0, plen - 1]))
+        self.slot_last_token[slot] = first
+        if not (r.kv_migrated and r.first_token_time is not None):
+            r.output_tokens = [first]   # a resumed request keeps its stream
         stats = None
         if "expert_ids" in aux:
             stats = np.asarray(aux["expert_ids"])[:, :, :plen]
@@ -189,14 +239,14 @@ class JaxBackend:
     def decode(self, active: Sequence[Tuple[int, Request]], now: float
                ) -> Tuple[Set[int], Optional[np.ndarray]]:
         self._sync_placement()
-        tokens = jnp.asarray(self.slot_last_token)[:, None]
-        pos = self.kv.positions()
+        tokens = self._put(self.slot_last_token[:, None])
+        pos = self._put(self.kv.positions())
         if self.kv_layout == "paged":
             for slot, _r in active:
                 self.kv.prepare_append(slot)     # alloc/CoW tail pages
             logits, new_pages, aux = self._jit_decode_paged(
-                self.params, tokens, self.kv.pages, self.kv.device_tables(),
-                pos, self._placements())
+                self.params, tokens, self.kv.pages,
+                self._put(self.kv.block_tables), pos, self._placements())
             self.kv.pages = new_pages
         else:
             logits, new_cache, aux = self._jit_decode(
@@ -208,6 +258,8 @@ class JaxBackend:
         for slot, r in active:
             rows.append(slot)
             self.slot_last_token[slot] = nxt[slot]
+            if r.output_tokens is not None:
+                r.output_tokens.append(int(nxt[slot]))
             self.kv.slot_len[slot] = min(self.kv.slot_len[slot] + 1,
                                          self.max_seq - 1)
             if self.eos_id is not None and nxt[slot] == self.eos_id:

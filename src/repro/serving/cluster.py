@@ -146,18 +146,29 @@ class Cluster:
         engine must not be silently dropped from the finished set (they only
         stop counting once ``fail_engine`` has drained and re-routed them).
         ``on_step(cluster, now)`` runs after each step — fault-injection
-        drills (restore an engine mid-drain) hook in here."""
+        drills (restore an engine mid-drain) hook in here.  Raises
+        ``RuntimeError`` when the cluster is still not drained after
+        ``max_steps`` steps: unfinished requests are an error, never a
+        short result."""
         now = t0
         for _ in range(max_steps):
             self.step(now)
             if on_step is not None:
                 on_step(self, now)
             now += dt
-            if (not self._in_transfer
-                    and all(e.num_active() == 0 and len(e.queue) == 0
-                            for e in self.engines.values())):
-                break
-        return self.finished
+            if self.drained():
+                return self.finished
+        raise RuntimeError(
+            f"not drained after {max_steps} steps: {self.pending()} requests "
+            f"unfinished ({len(self.finished)} finished)")
+
+    def pending(self) -> int:
+        """Requests still queued, running or on the KV wire, cluster-wide."""
+        return len(self._in_transfer) + sum(
+            e.num_active() + len(e.queue) for e in self.engines.values())
+
+    def drained(self) -> bool:
+        return self.pending() == 0
 
     # ---------------------------------------------------- prefill/decode hand-off
     def poll_handoffs(self, now: float) -> int:

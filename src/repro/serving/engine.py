@@ -43,14 +43,18 @@ class Engine:
                  dispatch_mode: str = "dense", expert_level: Any = _PRIVATE,
                  kv_layout: str = "slot", kv_block_size: int = 16,
                  kv_quant: Optional[str] = None, use_kernels: bool = False,
-                 role: str = "unified", prefill_mode: str = "chunked"):
+                 role: str = "unified", prefill_mode: str = "chunked",
+                 device: Any = None):
         """``expert_level`` should be the ONE ClusterExpertLevel shared by
         every engine of a cluster (core/gimbal.make_cluster_expert_level):
         experts are EP-sharded across all engines' devices (§V-A.1), so
         routed stats from every engine aggregate into the same tracker and
         all engines apply the same placements.  When omitted, the engine
         builds a private level over ``num_expert_devices`` devices (the
-        historical single-engine behaviour)."""
+        historical single-engine behaviour).
+
+        ``device``: the jax.Device this engine serves on (default: the first
+        device); its parameters and KV pool are placed there."""
         self.engine_id = engine_id
         self.cfg = model_cfg
         self.gcfg = gimbal_cfg or GimbalConfig()
@@ -69,7 +73,8 @@ class Engine:
                                   rebalancer=rebalancer,
                                   kv_layout=kv_layout,
                                   kv_block_size=kv_block_size,
-                                  kv_quant=kv_quant, use_kernels=use_kernels)
+                                  kv_quant=kv_quant, use_kernels=use_kernels,
+                                  device=device)
         self.core = SchedulerCore(self.backend, make_queue(variant, self.gcfg),
                                   self.gcfg, prefill_budget=prefill_budget,
                                   engine_id=engine_id, expert_level=rebalancer,

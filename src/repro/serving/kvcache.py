@@ -77,12 +77,14 @@ def write_slot(cache, slot_cache, slot, axes) -> Any:
 
 class SlotKVCache:
     def __init__(self, model_cfg: mcfg.ModelConfig, max_slots: int, max_seq: int,
-                 dtype=None):
+                 dtype=None, device=None):
         assert max_slots > 1, "slot cache requires max_slots > 1"
         self.model_cfg = model_cfg
         self.max_slots = max_slots
         self.max_seq = max_seq
         self.cache = M.init_cache(model_cfg, max_slots, max_seq, dtype)
+        if device is not None:
+            self.cache = jax.device_put(self.cache, device)
         self.write_axes = batch_axes(model_cfg, max_slots, max_seq, dtype)
         self.slot_len = np.zeros(max_slots, np.int64)     # tokens resident per slot
         self._free_heap: List[int] = list(range(max_slots))  # sorted => valid heap
@@ -121,27 +123,31 @@ class SlotKVCache:
     def kv_bytes_used(self) -> int:
         return int(self.slot_len.sum()) * self.model_cfg.kv_bytes_per_token()
 
-    def positions(self) -> jnp.ndarray:
-        return jnp.asarray(np.minimum(self.slot_len, self.max_seq - 1), jnp.int32)
+    def positions(self) -> np.ndarray:
+        """(max_slots,) int32 write position per slot (host array)."""
+        return np.minimum(self.slot_len, self.max_seq - 1).astype(np.int32)
 
 
 class PagedKVCache:
     """Paged device KV cache for homogeneous GQA attention stacks.
 
-    Layout: per-layer K/V pages of shape (L, P, BS, Hkv, D) where P is the
-    global pool size and BS the block size.  Physical page 0 is a reserved
+    Layout: per-layer head-major K/V pages of shape (L, P, Hkv, BS, D) where
+    P is the global pool size and BS the block size (head-major so the
+    flash-decode kernel's (BS, D) page tile is a legal TPU block; see
+    kernels/flash_decode.py).  Physical page 0 is a reserved
     garbage page: free/inactive slots' block-table rows point at it, so the
     full-batch decode scatter lands harmlessly there.  Full prompt blocks are
     refcounted and shared across slots keyed by the same chained block hashes
     the prefix cache uses (causal attention => identical prefixes produce
     identical K/V pages); a prefix hit pins the resident pages instead of
     re-writing them.  Optional int8 storage keeps a per-(layer, page) scale,
-    quantized with training/compression.py::quantize_int8.
+    quantized with training/compression.py::quantize_int8.  The pool lives
+    on ``device`` (default: JAX's default device).
     """
 
     def __init__(self, model_cfg: mcfg.ModelConfig, max_slots: int, max_seq: int,
                  *, block_size: int = 16, total_blocks: Optional[int] = None,
-                 dtype=None, quantize: bool = False):
+                 dtype=None, quantize: bool = False, device=None):
         cfg = model_cfg
         if (cfg.attention_type != "gqa" or cfg.is_ssm or cfg.is_hybrid
                 or cfg.is_encoder_decoder):
@@ -165,12 +171,13 @@ class PagedKVCache:
         hkv, d = cfg.num_kv_heads, cfg.head_dim
         store = jnp.int8 if quantize else (dtype or cfg.adtype)
         self.pages: Dict[str, jnp.ndarray] = {
-            "k": jnp.zeros((L, n_pages, block_size, hkv, d), store),
-            "v": jnp.zeros((L, n_pages, block_size, hkv, d), store),
+            "k": jnp.zeros((L, n_pages, hkv, block_size, d), store, device=device),
+            "v": jnp.zeros((L, n_pages, hkv, block_size, d), store, device=device),
         }
         if quantize:
-            self.pages["k_scale"] = jnp.zeros((L, n_pages), jnp.float32)
-            self.pages["v_scale"] = jnp.zeros((L, n_pages), jnp.float32)
+            for name in ("k_scale", "v_scale"):
+                self.pages[name] = jnp.zeros((L, n_pages), jnp.float32,
+                                             device=device)
 
         self.block_tables = np.zeros((max_slots, self.max_blocks), np.int32)
         self.slot_len = np.zeros(max_slots, np.int64)
@@ -285,7 +292,8 @@ class PagedKVCache:
                 src = jnp.pad(src, pad)
             L = src.shape[0]
             blocks = src[:, 0, start * bs:n * bs].reshape(
-                L, n - start, bs, src.shape[3], src.shape[4])
+                L, n - start, bs, src.shape[3], src.shape[4]
+            ).transpose(0, 1, 3, 2, 4)                       # head-major pages
             if self.quantized:
                 q, scale = self._quant(blocks)
                 self.pages[name] = self.pages[name].at[:, phys].set(q)
@@ -323,12 +331,10 @@ class PagedKVCache:
             if bidx < self._slot_shared[slot]:
                 self._slot_shared[slot] = bidx
 
-    # --- device-side views ------------------------------------------------------
-    def device_tables(self) -> jnp.ndarray:
-        return jnp.asarray(self.block_tables, jnp.int32)
-
-    def positions(self) -> jnp.ndarray:
-        return jnp.asarray(np.minimum(self.slot_len, self.max_seq - 1), jnp.int32)
+    # --- step inputs ------------------------------------------------------------
+    def positions(self) -> np.ndarray:
+        """(max_slots,) int32 write position per slot (host array)."""
+        return np.minimum(self.slot_len, self.max_seq - 1).astype(np.int32)
 
     # --- metrics (Alg. 1 signal) --------------------------------------------------
     def usage(self) -> float:

@@ -16,6 +16,7 @@ fault paths run without JAX compiles:
     sticky session workload (the campaign cell's fast twin).
 """
 import numpy as np
+import pytest
 
 from repro.core.types import GimbalConfig, Request
 from repro.core.gimbal import make_sim_expert_level
@@ -151,6 +152,19 @@ def test_run_until_drained_healthy_cluster_unaffected():
         c.submit(req(i, base=500 * i), 0.0)
     done = c.run_until_drained(t0=0.0, dt=0.05, max_steps=2000)
     assert len(done) == 4
+
+
+def test_run_until_drained_raises_when_requests_remain():
+    """Stranded work is an error, not a short finished list: an engine that
+    stays down keeps its requests, and the drain gives up loudly."""
+    c = make_cluster(n=2, variant="rr")
+    for i in range(4):
+        c.submit(req(i, base=1000 * i), 0.0)
+    c.engines[0].healthy = False               # never restored
+    with pytest.raises(RuntimeError, match="not drained after 200 steps"):
+        c.run_until_drained(t0=0.0, dt=0.05, max_steps=200)
+    assert c.pending() > 0 and not c.drained()
+    assert len(c.finished) < 4
 
 
 # --- end-to-end: combined beats rr on a sticky session workload --------------

@@ -153,13 +153,13 @@ def test_topk_router_replicated_splits_hot_expert():
 # --- paged flash-decode (ISSUE 8) ---------------------------------------------
 
 def _paged_case(seed, b, hq, hkv, d, bs, nb, dtype=jnp.float32):
-    """Random page pool + non-aliasing random block tables (page 0 reserved
-    as the garbage page, like PagedKVCache)."""
+    """Random head-major page pool + non-aliasing random block tables (page
+    0 reserved as the garbage page, like PagedKVCache)."""
     pool = b * nb + 1
     ks = jax.random.split(jax.random.key(seed), 3)
     q = jax.random.normal(ks[0], (b, hq, d), dtype)
-    k_pages = jax.random.normal(ks[1], (pool, bs, hkv, d), dtype)
-    v_pages = jax.random.normal(ks[2], (pool, bs, hkv, d), dtype)
+    k_pages = jax.random.normal(ks[1], (pool, hkv, bs, d), dtype)
+    v_pages = jax.random.normal(ks[2], (pool, hkv, bs, d), dtype)
     perm = np.random.default_rng(seed).permutation(pool - 1)[:b * nb] + 1
     tables = jnp.asarray(perm.reshape(b, nb), jnp.int32)
     return q, k_pages, v_pages, tables
@@ -214,8 +214,8 @@ def test_flash_decode_paged_matches_contiguous_slot_kernel():
     q, kp, vp, bt = _paged_case(13, b, hq, hkv, d, bs, nb)
     lengths = jnp.asarray([0, 17, 64], jnp.int32)
     paged = flash_decode_paged(q, kp, vp, bt, lengths, interpret=True)
-    k = kp[bt].reshape(b, nb * bs, hkv, d)
-    v = vp[bt].reshape(b, nb * bs, hkv, d)
+    k = kp[bt].transpose(0, 1, 3, 2, 4).reshape(b, nb * bs, hkv, d)
+    v = vp[bt].transpose(0, 1, 3, 2, 4).reshape(b, nb * bs, hkv, d)
     slot = flash_decode(q, k, v, lengths, block_s=16, interpret=True)
     np.testing.assert_allclose(np.asarray(paged), np.asarray(slot),
                                rtol=2e-4, atol=2e-4)
@@ -285,7 +285,8 @@ def test_moe_apply_fused_matches_dense():
     x = jnp.asarray(rng.normal(size=(2, 8, d)), jnp.float32)
     # identity placement: fused vs the dense one-hot einsum
     y_d, aux_d = moe_apply(params, cfg, x, None, "dense", return_stats=True)
-    y_f, aux_f = moe_apply(params, cfg, x, None, "fused", return_stats=True)
+    y_f, aux_f = moe_apply(params, cfg, x, None, "fused", return_stats=True,
+                           interpret=True)
     np.testing.assert_allclose(np.asarray(y_f), np.asarray(y_d),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(np.asarray(aux_f["expert_ids"]),
@@ -300,7 +301,7 @@ def test_moe_apply_fused_matches_dense():
     y_g, aux_g = moe_apply(slot_params, cfg, x, plc, "gather",
                            return_stats=True)
     y_f2, aux_f2 = moe_apply(slot_params, cfg, x, plc, "fused",
-                             return_stats=True)
+                             return_stats=True, interpret=True)
     np.testing.assert_allclose(np.asarray(y_f2), np.asarray(y_g),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(np.asarray(aux_f2["expert_ids"]),

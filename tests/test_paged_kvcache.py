@@ -152,7 +152,8 @@ def test_paged_int8_prefill_roundtrip():
         phys = kv.block_tables[s, :2]
         got = dequantize_int8(kv.pages[n][:, phys],
                               kv.pages[n + "_scale"][:, phys, None, None, None])
-        want = np.asarray(cache["layers"][n][:, 0]).reshape(L, 2, 16, H, D)
+        want = np.asarray(cache["layers"][n][:, 0]).reshape(
+            L, 2, 16, H, D).transpose(0, 1, 3, 2, 4)     # head-major pages
         np.testing.assert_allclose(np.asarray(got), want, atol=2e-2)
     # scale bookkeeping doubles the byte accounting honestly
     assert kv.kv_bytes_used() > 0

@@ -19,6 +19,7 @@ import pytest
 pytestmark = pytest.mark.slow
 
 from repro.distributed.context import ShardCtx, shard_ctx
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models.config import ModelConfig
 
@@ -37,7 +38,7 @@ def test_paired_local_global_matches_baseline():
     toks = jax.random.randint(jax.random.key(1), (2, 16), 0, cfg.vocab_size)
 
     base, _ = M.forward_train(params, cfg, toks)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ctx = ShardCtx(mesh=mesh, batch_axes=("data",), paired_lg=True,
                    seq_parallel=False)
     with shard_ctx(ctx):
@@ -63,6 +64,7 @@ _MULTIDEV = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.context import ShardCtx, shard_ctx
+    from repro.launch.mesh import make_mesh
     from repro.models import model as M, moe_sharded
     from repro.models.moe import init_moe, moe_apply, ExpertPlacement
     from repro.models.config import ModelConfig
@@ -75,7 +77,7 @@ _MULTIDEV = textwrap.dedent("""
     x = jax.random.normal(jax.random.key(1), (8, 4, cfg.d_model), jnp.float32)
     ref, _ = moe_apply(params, cfg, x, dispatch_mode="gather")
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     outs = {}
     for mode in ("gather", "tokengather", "a2a"):
         ctx = ShardCtx(mesh=mesh, batch_axes=("data",), ep_mode=mode,

@@ -18,20 +18,11 @@ from repro.models.config import SHAPE_CELLS
 pytestmark = pytest.mark.slow
 
 
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across jax versions: newer releases take (sizes, names),
-    older ones a tuple of (name, size) pairs."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
 def abstract_ctx(multi_pod=False):
     if multi_pod:
-        mesh = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+        mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
         return ShardCtx(mesh=mesh, batch_axes=("pod", "data"))
-    mesh = _abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     return ShardCtx(mesh=mesh, batch_axes=("data",))
 
 
