@@ -31,6 +31,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.affinity import AffinityTracker, synthetic_stats
 from repro.core.placement import (eplb_placement, eplb_placement_rep,
                                   gimbal_placement, gimbal_placement_rep,
@@ -102,15 +103,17 @@ class ExpertRebalancer:
         engine into one statistics pool; synthetic prior mass (if seeded)
         decays away at the tracker's exponential rate as real traffic
         arrives."""
-        self.tracker.update(expert_ids)
+        with trace.span("expert.observe"):
+            self.tracker.update(expert_ids)
 
     def tick(self) -> Optional[np.ndarray]:
         """Advance one engine step; returns a NEW slot map when a relocation
         fires (Alg. 3 lines 6-9: every tau steps), else None."""
-        self.step += 1
-        if self.policy == "static" or self.step % self.cfg.tau != 0:
-            return None
-        return self.rebalance()
+        with trace.span("expert.tick"):
+            self.step += 1
+            if self.policy == "static" or self.step % self.cfg.tau != 0:
+                return None
+            return self.rebalance()
 
     def rebalance(self) -> np.ndarray:
         A, W = self.tracker.A, self.tracker.W

@@ -50,6 +50,7 @@ from typing import Dict, List, Optional, Protocol, Sequence, Set, Tuple
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.predictor import make_predictor
 from repro.core.preempt import (eligible_victims, reset_for_resume,
                                 select_victim)
@@ -292,6 +293,7 @@ class SchedulerCore:
             # regardless of what this engine's local cache holds
             r._cached = r.prompt_len
         self.queue.push(r)
+        trace.wait_open(r.req_id)
         return True
 
     # ------------------------------------------------------------------ metrics
@@ -510,49 +512,55 @@ class SchedulerCore:
 
         Returns (admitted, victims); victims must be re-queued by the caller
         only after admission completes."""
-        order = self.queue.reorder(now)
-        # layered mode: requests mid-pipeline re-touch their tokens every
-        # micro-step, so in-flight charges stay against the budget until
-        # their last layer — bounding total concurrent prefill work to one
-        # budget's worth across the pipeline (chunked: always 0)
-        budget = self.prefill_budget - sum(p.tokens for p in self._prefilling)
-        admitted: List[Request] = []
-        victims: List[Request] = []
-        blocked_rank = _UNBLOCKED_RANK      # most-urgent rank blocked so far
-        for r in list(order):
-            if r.rank >= blocked_rank:
-                continue
-            need = self._charge(r)
-            if need > budget and (admitted or self._prefilling):
-                if self.gcfg.enable_preemption:
-                    # budget-blocked head: strictly-more-urgent requests
-                    # behind it may still be scanned (symmetric with the
-                    # capacity-blocked case below)
-                    blocked_rank = min(blocked_rank, r.rank)
+        with trace.span("sched.schedule") as sp:
+            sp.note("queue", len(self.queue))
+            order = self.queue.reorder(now)
+            # layered mode: requests mid-pipeline re-touch their tokens
+            # every micro-step, so in-flight charges stay against the budget
+            # until their last layer — bounding total concurrent prefill
+            # work to one budget's worth across the pipeline (chunked: 0)
+            budget = self.prefill_budget - sum(p.tokens
+                                               for p in self._prefilling)
+            admitted: List[Request] = []
+            victims: List[Request] = []
+            blocked_rank = _UNBLOCKED_RANK    # most-urgent rank blocked so far
+            for r in list(order):
+                if r.rank >= blocked_rank:
                     continue
-                break
-            # priority preemption: evict lower-class running work to make
-            # room, but only for requests admissible this iteration (budget-
-            # gated above) and only when eviction can actually unblock r
-            if (self.gcfg.enable_preemption
-                    and self._blocked(r, len(admitted))
-                    and self._eviction_unblocks(r, len(admitted))):
-                while self._blocked(r, len(admitted)):
-                    v = self._evict_for(r.rank)
-                    if v is None:
-                        break
-                    victims.append(v)
-            if self._blocked(r, len(admitted)):
-                if self.gcfg.enable_preemption:
-                    blocked_rank = min(blocked_rank, r.rank)
-                    continue
-                break
-            budget -= need
-            admitted.append(r)
-            self.kv_tokens += self._kv_demand(r)
-            self._admit_blocks(r)
-            self.queue.remove(r)
-            self.events.append(SchedEvent("admit", self.steps, r.req_id))
+                need = self._charge(r)
+                if need > budget and (admitted or self._prefilling):
+                    if self.gcfg.enable_preemption:
+                        # budget-blocked head: strictly-more-urgent requests
+                        # behind it may still be scanned (symmetric with the
+                        # capacity-blocked case below)
+                        blocked_rank = min(blocked_rank, r.rank)
+                        continue
+                    break
+                # priority preemption: evict lower-class running work to
+                # make room, but only for requests admissible this iteration
+                # (budget-gated above) and only when eviction can actually
+                # unblock r
+                if (self.gcfg.enable_preemption
+                        and self._blocked(r, len(admitted))
+                        and self._eviction_unblocks(r, len(admitted))):
+                    while self._blocked(r, len(admitted)):
+                        v = self._evict_for(r.rank)
+                        if v is None:
+                            break
+                        victims.append(v)
+                if self._blocked(r, len(admitted)):
+                    if self.gcfg.enable_preemption:
+                        blocked_rank = min(blocked_rank, r.rank)
+                        continue
+                    break
+                budget -= need
+                admitted.append(r)
+                self.kv_tokens += self._kv_demand(r)
+                self._admit_blocks(r)
+                self.queue.remove(r)
+                trace.wait_close(r.req_id)
+                self.events.append(SchedEvent("admit", self.steps, r.req_id))
+            sp.note("admitted", len(admitted))
         return admitted, victims
 
     def _begin(self, r: Request, now: float, end: float,
@@ -589,88 +597,92 @@ class SchedulerCore:
         (end timestamp, requests finished this step)."""
         if not self.healthy:
             return now, []
-        admitted, victims = self.schedule(now)
-        # the decode batch: admitted in a PRIOR step and not evicted above
-        # (schedule() runs first, so victims never decode after losing KV)
-        decoding = list(self.running)
-        avg_ctx = (float(np.mean([self.ctx_tokens[seq.r.req_id]
-                                  for seq in decoding])) if decoding else 0.0)
-        if self.prefill_mode == "layered":
-            # admitted requests with real prefill work enter the layer
-            # pipeline; the admission step is their first micro-step
-            for r in admitted:
-                if self._charge(r) > 0:
-                    r.engine_id = self.engine_id
-                    self.ctx_tokens[r.req_id] = self._kv_demand(r)
-                    self._prefilling.append(
-                        LayeredPrefill(r, self._charge(r), 0, now))
-            # this iteration = one decode step + ONE layer of prefill per
-            # in-flight request (decode stalls for a layer, not a chunk)
-            end = self.backend.step_time(
-                now, 0, len(decoding), avg_ctx, queue_len=len(self.queue),
-                layer_jobs=[p.tokens for p in self._prefilling])
-            # nothing-to-prefill admits (fully cached / KV-migrated
-            # hand-offs) skip the pipeline and start like a chunked admit
-            for r in admitted:
-                if self._charge(r) == 0:
+        with trace.span("sched.step"):
+            admitted, victims = self.schedule(now)
+            # the decode batch: admitted in a PRIOR step and not evicted above
+            # (schedule() runs first, so victims never decode after losing KV)
+            decoding = list(self.running)
+            avg_ctx = (float(np.mean([self.ctx_tokens[seq.r.req_id]
+                                      for seq in decoding])) if decoding else 0.0)
+            if self.prefill_mode == "layered":
+                # admitted requests with real prefill work enter the layer
+                # pipeline; the admission step is their first micro-step
+                for r in admitted:
+                    if self._charge(r) > 0:
+                        r.engine_id = self.engine_id
+                        self.ctx_tokens[r.req_id] = self._kv_demand(r)
+                        self._prefilling.append(
+                            LayeredPrefill(r, self._charge(r), 0, now))
+                # this iteration = one decode step + ONE layer of prefill per
+                # in-flight request (decode stalls for a layer, not a chunk)
+                end = self.backend.step_time(
+                    now, 0, len(decoding), avg_ctx, queue_len=len(self.queue),
+                    layer_jobs=[p.tokens for p in self._prefilling])
+                # nothing-to-prefill admits (fully cached / KV-migrated
+                # hand-offs) skip the pipeline and start like a chunked admit
+                for r in admitted:
+                    if self._charge(r) == 0:
+                        self._begin(r, now, end)
+                # advance every in-flight prefill one layer; completions emit
+                # their first token at `end` and decode from the next step
+                for p in list(self._prefilling):
+                    p.layers_done += 1
+                    if p.layers_done >= self.n_layers:
+                        self._prefilling.remove(p)
+                        self._begin(p.r, now, end, admit_time=p.admit_time)
+            else:
+                prefill_tokens = sum(self._charge(r) for r in admitted)
+                end = self.backend.step_time(now, prefill_tokens, len(decoding),
+                                             avg_ctx, queue_len=len(self.queue))
+                # admitted requests prefill; first token materializes at `end`
+                for r in admitted:
                     self._begin(r, now, end)
-            # advance every in-flight prefill one layer; completions emit
-            # their first token at `end` and decode from the next step
-            for p in list(self._prefilling):
-                p.layers_done += 1
-                if p.layers_done >= self.n_layers:
-                    self._prefilling.remove(p)
-                    self._begin(p.r, now, end, admit_time=p.admit_time)
-        else:
-            prefill_tokens = sum(self._charge(r) for r in admitted)
-            end = self.backend.step_time(now, prefill_tokens, len(decoding),
-                                         avg_ctx, queue_len=len(self.queue))
-            # admitted requests prefill; first token materializes at `end`
-            for r in admitted:
-                self._begin(r, now, end)
-        # victims re-queue only AFTER admission (see _evict_for)
-        self.queue.extend(victims)
-        # one decode step over every previously-running request
-        finished: List[Request] = []
-        if decoding:
-            eos, stats = self.backend.decode(
-                [(seq.handle, seq.r) for seq in decoding], now)
-            if stats is not None and self.expert is not None:
-                self.expert.observe(stats)
-            cap = self.backend.max_ctx_tokens
-            for seq in decoding:
-                r = seq.r
-                r.generated += 1
-                self._grow_ctx(r.req_id)    # decode growth holds KV too
-                # finish-at-cap: once this request's KV slot is full there is
-                # nowhere to write the next token — the request MUST finish,
-                # or decode would clamp KV writes to the same position
-                # forever and silently corrupt every later token (the
-                # pre-fix behaviour).  Resident tokens = the prompt the
-                # backend keeps (truncated to cap-1, leaving one write
-                # position) + one committed write per decode step; the
-                # decode that fills the last position is the final one.
-                at_cap = cap is not None and \
-                    min(r.prompt_len, cap - 1) + (r.generated - 1) >= cap
-                if (r.generated >= r.max_new_tokens or r.req_id in eos
-                        or at_cap):
-                    r.finish_time = end
-                    finished.append(r)
-                    self.running.remove(seq)
-                    self.kv_tokens -= self.ctx_tokens.pop(r.req_id)
-                    self._release_blocks(r.req_id)
-                    self.backend.release(seq.handle, r)
-                    self.events.append(SchedEvent("finish", self.steps, r.req_id))
-                    self.slo.observe(r)
-                    if self.predictor is not None:
-                        self.predictor.observe(r)   # histogram EMA update
-        # expert-level tick (Alg. 3 lines 6-9)
-        self.steps += 1
-        if self.expert is not None:
-            new_perm = self.expert.tick()
-            if new_perm is not None:
-                self.backend.apply_placement(new_perm)
-        return end, finished
+            # victims re-queue only AFTER admission (see _evict_for)
+            self.queue.extend(victims)
+            for v in victims:
+                trace.wait_open(v.req_id)
+            # one decode step over every previously-running request
+            finished: List[Request] = []
+            if decoding:
+                eos, stats = self.backend.decode(
+                    [(seq.handle, seq.r) for seq in decoding], now)
+                if stats is not None and self.expert is not None:
+                    self.expert.observe(stats)
+                cap = self.backend.max_ctx_tokens
+                for seq in decoding:
+                    r = seq.r
+                    r.generated += 1
+                    self._grow_ctx(r.req_id)    # decode growth holds KV too
+                    # finish-at-cap: once this request's KV slot is full there
+                    # is nowhere to write the next token — the request MUST finish,
+                    # or decode would clamp KV writes to the same position
+                    # forever and silently corrupt every later token (the
+                    # pre-fix behaviour).  Resident tokens = the prompt the
+                    # backend keeps (truncated to cap-1, leaving one write
+                    # position) + one committed write per decode step; the
+                    # decode that fills the last position is the final one.
+                    at_cap = cap is not None and \
+                        min(r.prompt_len, cap - 1) + (r.generated - 1) >= cap
+                    if (r.generated >= r.max_new_tokens or r.req_id in eos
+                            or at_cap):
+                        r.finish_time = end
+                        finished.append(r)
+                        self.running.remove(seq)
+                        self.kv_tokens -= self.ctx_tokens.pop(r.req_id)
+                        self._release_blocks(r.req_id)
+                        self.backend.release(seq.handle, r)
+                        self.events.append(
+                            SchedEvent("finish", self.steps, r.req_id))
+                        self.slo.observe(r)
+                        if self.predictor is not None:
+                            self.predictor.observe(r)   # histogram EMA update
+            # expert-level tick (Alg. 3 lines 6-9)
+            self.steps += 1
+            if self.expert is not None:
+                new_perm = self.expert.tick()
+                if new_perm is not None:
+                    self.backend.apply_placement(new_perm)
+            return end, finished
 
     # ------------------------------------------------------------------ fault tolerance
     def drain(self, migrate: bool = False) -> List[Request]:
@@ -686,6 +698,8 @@ class SchedulerCore:
         latency semantics of a KV transfer; the live backend still re-runs
         the prompt prefill physically rather than receiving pages.)"""
         out = self.queue.drain()
+        for r in out:
+            trace.wait_drop(r.req_id)
         # mid-pipeline layered prefills: no first token yet, and partial
         # layer progress is NOT transferable KV — they re-queue elsewhere
         # as fresh work regardless of ``migrate``

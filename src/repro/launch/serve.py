@@ -14,6 +14,12 @@ Replica *i* serves from ``jax.devices()[i]`` (round-robin when there are
 fewer devices than engines), and every replica holds the same weights.
 
 The run exits non-zero when any request is still unfinished at ``HORIZON``.
+
+The summary's TTFT, TPOT and rate are on the logical clock (each step
+advances it a fixed ``dt``), not measured.  Beside them it prints what the
+in-program recorder (``repro.core.trace``) measured on the host's clock:
+the mean queue wait, prefill and decode step, and the compiles among them
+(this entry point compiles as it serves).
 """
 from __future__ import annotations
 
@@ -27,6 +33,7 @@ from typing import Optional, Sequence
 import jax
 
 from repro.configs import at_depth, get_config, get_smoke_config, list_archs
+from repro.core import trace as tracer
 from repro.core.types import GimbalConfig
 from repro.distributed.fault import HealthConfig, HealthMonitor
 from repro.models import model as M
@@ -38,6 +45,21 @@ from repro.workloads.sharegpt import sharegpt_trace
 
 REPO_ROOT = Path(__file__).resolve().parents[3]
 HORIZON = 120.0        # logical seconds served before unfinished requests fail
+# the summary's measured means: label -> recorder span
+MEASURED = {"queue wait": "request.queued", "prefill": "backend.prefill",
+            "decode step": "backend.decode", "compile": "compile"}
+
+
+def measured_means(records) -> dict:
+    """``{span: (count, mean ms)}`` of the recorder's records for each span
+    the summary prints."""
+    by = {name: [] for name in MEASURED.values()}
+    for r in records:
+        got = by.get(r.name[len(tracer.PREFIX):])
+        if got is not None:
+            got.append(r.ms)
+    return {k: (len(v), sum(v) / len(v) if v else 0.0)
+            for k, v in by.items()}
 
 
 def use_compile_cache() -> str:
@@ -131,32 +153,41 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     trace = [copy.copy(r) for r in trace]
     i, now, dt = 0, 0.0, 0.05
     failed_at = None
-    while now <= HORIZON:
-        while i < len(trace) and trace[i].arrival_time <= now:
-            cluster.submit(trace[i], now)
-            i += 1
-        cluster.step(now)
-        monitor.observe(cluster.bus.snapshot(now), now)
-        for eid in monitor.check(now):
-            print(f"[serve] t={now:.2f} engine {eid} DEAD -> re-routing")
-            cluster.fail_engine(eid, now)
-        if args.fail_engine >= 0 and failed_at is None and i >= len(trace) // 2:
-            eid = args.fail_engine
-            print(f"[serve] t={now:.2f} injecting failure of engine {eid}")
-            moved = cluster.fail_engine(eid, now)
-            print(f"[serve] re-routed {moved} requests")
-            failed_at = now
-        now += dt
-        if i >= len(trace) and cluster.drained():
-            break
-
+    tracer.enable()
+    try:
+        while now <= HORIZON:
+            while i < len(trace) and trace[i].arrival_time <= now:
+                cluster.submit(trace[i], now)
+                i += 1
+            cluster.step(now)
+            monitor.observe(cluster.bus.snapshot(now), now)
+            for eid in monitor.check(now):
+                print(f"[serve] t={now:.2f} engine {eid} DEAD -> re-routing")
+                cluster.fail_engine(eid, now)
+            if (args.fail_engine >= 0 and failed_at is None
+                    and i >= len(trace) // 2):
+                eid = args.fail_engine
+                print(f"[serve] t={now:.2f} injecting failure of engine {eid}")
+                moved = cluster.fail_engine(eid, now)
+                print(f"[serve] re-routed {moved} requests")
+                failed_at = now
+            now += dt
+            if i >= len(trace) and cluster.drained():
+                break
+    finally:
+        tracer.disable()
+    spans = measured_means(tracer.drain())
     rep = cluster.report()
     pf = cluster.prefix_stats()
     relocs = sum(e.relocations for e in cluster.engines.values())
     print(f"[serve] {args.variant} on {cfg.name} ({args.size}, "
-          f"{cfg.num_layers} layers): {rep.n}/{len(trace)} done | "
-          f"TTFT mean {rep.mean_ttft:.3f}s p99 {rep.p99_ttft:.3f}s | "
-          f"TPOT {rep.mean_tpot*1e3:.1f}ms | {rep.throughput_tok_s:.0f} tok/s")
+          f"{cfg.num_layers} layers): {rep.n}/{len(trace)} done | logical "
+          f"clock, {dt:g} s a step: TTFT mean {rep.mean_ttft:.3f}s p99 "
+          f"{rep.p99_ttft:.3f}s | TPOT {rep.mean_tpot*1e3:.1f}ms | "
+          f"{rep.throughput_tok_s:.0f} tok/s")
+    print("[serve] measured (host clock): " + " | ".join(
+        f"{label} mean {spans[name][1]:.3f}ms n={spans[name][0]}"
+        for label, name in MEASURED.items()))
     print(f"[serve] prefix hits {pf['hit_blocks']} "
           f"(rate {100*pf['hit_rate']:.1f}%) | expert relocations {relocs}")
     unfinished = len(trace) - i + cluster.pending()

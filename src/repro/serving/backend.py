@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.core.eplb import ExpertRebalancer
 from repro.core.types import Request
 from repro.models import config as mcfg
@@ -198,76 +199,83 @@ class JaxBackend:
     # ------------------------------------------------------------------ Backend protocol
     def start(self, r: Request, now: float
               ) -> Tuple[int, Optional[np.ndarray]]:
-        self._sync_placement()
-        plen = min(r.prompt_len, self.max_seq - 1)
-        if r.prompt_tokens is not None:
-            toks = np.asarray(r.prompt_tokens, np.int32).reshape(-1)[:plen]
-        else:
-            rng = np.random.default_rng(r.req_id)
-            toks = rng.integers(0, self.cfg.vocab_size, plen).astype(np.int32)
-        if self.kv_layout == "paged":
-            # share only when the core's block accounting also shared: real
-            # tokens, not a migrated sequence (its KV travelled, all private)
-            share = (r.prompt_tokens is not None
-                     and not getattr(r, "kv_migrated", False))
-            slot = self.kv.alloc(plen, toks.tolist() if share else None)
-        else:
-            slot = self.kv.alloc()
-        assert slot is not None, "SchedulerCore admitted past slot capacity"
-        bl = _bucket(plen)
-        padded = np.zeros((1, bl), np.int32)
-        padded[0, :plen] = toks
-        fn = self._prefill_for_bucket(bl)
-        logits, slot_cache, aux = fn(self.params, self._put(padded),
-                                     self._placements())
-        if self.kv_layout == "paged":
-            self.kv.write_prefill(slot, slot_cache)
-        else:
-            self.kv.cache = write_slot(self.kv.cache, slot_cache, slot,
-                                       self.kv.write_axes)
-        self.slot_req[slot] = r
-        self.kv.slot_len[slot] = plen
-        first = int(jnp.argmax(logits[0, plen - 1]))
-        self.slot_last_token[slot] = first
-        if not (r.kv_migrated and r.first_token_time is not None):
-            r.output_tokens = [first]   # a resumed request keeps its stream
-        stats = None
-        if "expert_ids" in aux:
-            stats = np.asarray(aux["expert_ids"])[:, :, :plen]
-        return slot, stats
+        with trace.span("backend.prefill", r.req_id) as sp:
+            self._sync_placement()
+            plen = min(r.prompt_len, self.max_seq - 1)
+            if r.prompt_tokens is not None:
+                toks = np.asarray(r.prompt_tokens, np.int32).reshape(-1)[:plen]
+            else:
+                rng = np.random.default_rng(r.req_id)
+                toks = rng.integers(0, self.cfg.vocab_size, plen).astype(np.int32)
+            if self.kv_layout == "paged":
+                # share only when the core's block accounting also shared: real
+                # tokens, not a migrated sequence (its KV travelled, all private)
+                share = (r.prompt_tokens is not None
+                         and not getattr(r, "kv_migrated", False))
+                slot = self.kv.alloc(plen, toks.tolist() if share else None)
+            else:
+                slot = self.kv.alloc()
+            assert slot is not None, "SchedulerCore admitted past slot capacity"
+            bl = _bucket(plen)
+            sp.note("plen", plen)
+            sp.note("bucket", bl)
+            padded = np.zeros((1, bl), np.int32)
+            padded[0, :plen] = toks
+            fn = self._prefill_for_bucket(bl)
+            logits, slot_cache, aux = fn(self.params, self._put(padded),
+                                         self._placements())
+            if self.kv_layout == "paged":
+                self.kv.write_prefill(slot, slot_cache)
+            else:
+                self.kv.cache = write_slot(self.kv.cache, slot_cache, slot,
+                                           self.kv.write_axes)
+            self.slot_req[slot] = r
+            self.kv.slot_len[slot] = plen
+            first = int(jnp.argmax(logits[0, plen - 1]))
+            self.slot_last_token[slot] = first
+            if not (r.kv_migrated and r.first_token_time is not None):
+                r.output_tokens = [first]   # a resumed request keeps its stream
+            stats = None
+            if "expert_ids" in aux:
+                stats = np.asarray(aux["expert_ids"])[:, :, :plen]
+            return slot, stats
 
     def decode(self, active: Sequence[Tuple[int, Request]], now: float
                ) -> Tuple[Set[int], Optional[np.ndarray]]:
-        self._sync_placement()
-        tokens = self._put(self.slot_last_token[:, None])
-        pos = self._put(self.kv.positions())
-        if self.kv_layout == "paged":
-            for slot, _r in active:
-                self.kv.prepare_append(slot)     # alloc/CoW tail pages
-            logits, new_pages, aux = self._jit_decode_paged(
-                self.params, tokens, self.kv.pages,
-                self._put(self.kv.block_tables), pos, self._placements())
-            self.kv.pages = new_pages
-        else:
-            logits, new_cache, aux = self._jit_decode(
-                self.params, tokens, self.kv.cache, pos, self._placements())
-            self.kv.cache = new_cache
-        nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
-        eos: Set[int] = set()
-        rows = []
-        for slot, r in active:
-            rows.append(slot)
-            self.slot_last_token[slot] = nxt[slot]
-            if r.output_tokens is not None:
-                r.output_tokens.append(int(nxt[slot]))
-            self.kv.slot_len[slot] = min(self.kv.slot_len[slot] + 1,
-                                         self.max_seq - 1)
-            if self.eos_id is not None and nxt[slot] == self.eos_id:
-                eos.add(r.req_id)
-        stats = None
-        if "expert_ids" in aux and rows:
-            stats = np.asarray(aux["expert_ids"])[:, rows]   # (L, B, 1, K)
-        return eos, stats
+        with trace.span("backend.decode") as sp:
+            sp.note("rows", len(active))
+            sp.note("max_slots", self.max_slots)
+            self._sync_placement()
+            tokens = self._put(self.slot_last_token[:, None])
+            pos = self._put(self.kv.positions())
+            if self.kv_layout == "paged":
+                for slot, _r in active:
+                    self.kv.prepare_append(slot)     # alloc/CoW tail pages
+                logits, new_pages, aux = self._jit_decode_paged(
+                    self.params, tokens, self.kv.pages,
+                    self._put(self.kv.block_tables), pos, self._placements())
+                self.kv.pages = new_pages
+            else:
+                logits, new_cache, aux = self._jit_decode(
+                    self.params, tokens, self.kv.cache, pos, self._placements())
+                self.kv.cache = new_cache
+            with trace.span("backend.decode.wait"):
+                nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
+            eos: Set[int] = set()
+            rows = []
+            for slot, r in active:
+                rows.append(slot)
+                self.slot_last_token[slot] = nxt[slot]
+                if r.output_tokens is not None:
+                    r.output_tokens.append(int(nxt[slot]))
+                self.kv.slot_len[slot] = min(self.kv.slot_len[slot] + 1,
+                                             self.max_seq - 1)
+                if self.eos_id is not None and nxt[slot] == self.eos_id:
+                    eos.add(r.req_id)
+            stats = None
+            if "expert_ids" in aux and rows:
+                stats = np.asarray(aux["expert_ids"])[:, rows]   # (L, B, 1, K)
+            return eos, stats
 
     def release(self, handle: int, r: Request) -> None:
         self.slot_req[handle] = None
@@ -313,30 +321,31 @@ class JaxBackend:
         Param trees without a stacked 'moe' block (non-MoE or interleaved
         layouts this backend doesn't relocate) are left untouched and do NOT
         count as a relocation."""
-        blocks = self.params["blocks"]
-        if "moe" not in blocks:
-            return
-        new_map = np.asarray(new_map)
-        # weights are currently laid out for the PREVIOUS slot map (initial
-        # layout == identity: slot s holds logical expert s)
-        old_map = self._applied_map
-        if old_map is None:
-            old_map = np.arange(self.cfg.num_experts)
-        if np.array_equal(old_map, new_map):
-            return                  # already laid out — not a relocation
-        self.relocations += 1
-        # each new slot gathers from ONE old slot holding its expert (the
-        # expert's first old slot — every expert has >= 1)
-        old_primary = np.full(self.cfg.num_experts, -1, np.int64)
-        for s in range(len(old_map) - 1, -1, -1):
-            old_primary[int(old_map[s])] = s
-        gather_idx = old_primary[new_map]
-        assert (gather_idx >= 0).all(), "new placement names an unknown expert"
-        moe = dict(blocks["moe"])
-        for name in ("w_gate", "w_up", "w_down"):
-            moe[name] = blocks["moe"][name][:, gather_idx]
-        blocks = dict(blocks)
-        blocks["moe"] = moe
-        self.params = dict(self.params)
-        self.params["blocks"] = blocks
-        self._applied_map = new_map.copy()
+        with trace.span("backend.relocate"):
+            blocks = self.params["blocks"]
+            if "moe" not in blocks:
+                return
+            new_map = np.asarray(new_map)
+            # weights are currently laid out for the PREVIOUS slot map (initial
+            # layout == identity: slot s holds logical expert s)
+            old_map = self._applied_map
+            if old_map is None:
+                old_map = np.arange(self.cfg.num_experts)
+            if np.array_equal(old_map, new_map):
+                return                  # already laid out — not a relocation
+            self.relocations += 1
+            # each new slot gathers from ONE old slot holding its expert (the
+            # expert's first old slot — every expert has >= 1)
+            old_primary = np.full(self.cfg.num_experts, -1, np.int64)
+            for s in range(len(old_map) - 1, -1, -1):
+                old_primary[int(old_map[s])] = s
+            gather_idx = old_primary[new_map]
+            assert (gather_idx >= 0).all(), "new placement names an unknown expert"
+            moe = dict(blocks["moe"])
+            for name in ("w_gate", "w_up", "w_down"):
+                moe[name] = blocks["moe"][name][:, gather_idx]
+            blocks = dict(blocks)
+            blocks["moe"] = moe
+            self.params = dict(self.params)
+            self.params["blocks"] = blocks
+            self._applied_map = new_map.copy()

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional, Sequence
 
+from repro.core import trace
 from repro.core.dispatch import DispatchCore
 from repro.core.slo import SLOTracker
 from repro.core.types import GimbalConfig, Request
@@ -119,21 +120,22 @@ class Cluster:
     # ------------------------------------------------------------------ execution
     def step(self, now: float) -> List[Request]:
         done: List[Request] = []
-        for e in list(self.engines.values()):
-            if not e.healthy:
-                continue
-            if now < self._ready_at.get(e.engine_id, now):
-                # warm-up: the engine is alive (heartbeats flow, it can be
-                # dispatched to and queue work) but serves nothing until its
-                # expert placement has been materialised
+        with trace.span("cluster.step"):
+            for e in list(self.engines.values()):
+                if not e.healthy:
+                    continue
+                if now < self._ready_at.get(e.engine_id, now):
+                    # warm-up: the engine is alive (heartbeats flow, it can
+                    # be dispatched to and queue work) but serves nothing
+                    # until its expert placement has been materialised
+                    self.bus.publish(e.metrics(now))
+                    continue
+                done.extend(e.step(now))
                 self.bus.publish(e.metrics(now))
-                continue
-            done.extend(e.step(now))
-            self.bus.publish(e.metrics(now))
-        self.poll_handoffs(now)
-        self._maybe_hedge(now)
-        self.health_check(now)
-        self.autoscale(now)
+            self.poll_handoffs(now)
+            self._maybe_hedge(now)
+            self.health_check(now)
+            self.autoscale(now)
         self.finished.extend(done)
         return done
 
@@ -420,9 +422,6 @@ class Cluster:
             slo.merge(e.core.slo)
         return slo.snapshot()
 
-    def preemption_stats(self) -> Dict[str, int]:
-        return {"preemptions": sum(e.preemptions for e in self._all_engines())}
-
     def hedge_stats(self) -> Dict[str, int]:
         """Straggler-mitigation counters: total hedged re-dispatches (each
         engine counts requests hedged AWAY from its queue)."""
@@ -439,15 +438,6 @@ class Cluster:
                     "bytes_moved": 0}
         return {"moe_mult": lvl.moe_mult, "cross_frac": lvl.cross_frac,
                 "migrations": lvl.migrations, "bytes_moved": lvl.bytes_moved}
-
-    def dispatch_stats(self) -> Dict[str, float]:
-        """Engine-level dispatch telemetry: assignment count and directory
-        occupancy per engine (the assignment stream itself is
-        ``self.dispatch.assignment_log()``)."""
-        d = self.dispatch
-        return {"assignments": len(d.assignments),
-                "directory_blocks": {eid: d.directory.blocks_held(eid)
-                                     for eid in self.engines}}
 
     def kv_transfer_stats(self) -> Dict[str, float]:
         """Disaggregated hand-off telemetry: delivered transfer count, KV
