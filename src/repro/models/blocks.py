@@ -18,13 +18,25 @@ from repro.models.config import ModelConfig
 from repro.models.layers import ffn_apply, init_ffn, init_rms_norm, rms_norm
 
 
+def _moe_sharded(cfg: ModelConfig) -> bool:
+    ctx = current_ctx()
+    return ctx is not None and cfg.num_experts % ctx.tp == 0
+
+
+def moe_reads_stacked(cfg: ModelConfig, dispatch_mode: str) -> bool:
+    """Whether the MoE layers take the whole stack's expert weights and a
+    layer index rather than one layer's slice: under the single-device
+    "fused" dispatch, whose expert GEMMs are Pallas calls (moe_gemm)."""
+    return cfg.is_moe and dispatch_mode == "fused" and not _moe_sharded(cfg)
+
+
 def _moe(p, cfg, h, placement, dispatch_mode, stats, interpret):
     """Dispatch to the shard_map expert-parallel path when a shard context is
     active (distributed lowering), else the single-device reference path.
     ``interpret`` runs the "fused" dispatch's Pallas kernels in the
     interpreter (CPU) instead of compiling them."""
     ctx = current_ctx()
-    if ctx is not None and cfg.num_experts % ctx.tp == 0:
+    if _moe_sharded(cfg):
         from repro.models.moe_sharded import moe_apply_sharded
         return moe_apply_sharded(p, cfg, h, placement, ctx, stats)
     return moe_lib.moe_apply(p, cfg, h, placement, dispatch_mode, stats,

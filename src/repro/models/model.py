@@ -170,6 +170,30 @@ def _placement_stack(cfg: ModelConfig, placements) -> Optional[jax.Array]:
     return jnp.asarray(placements, jnp.int32)
 
 
+def _hoist_expert_weights(cfg: ModelConfig, blocks, dispatch_mode: str):
+    """Take the (L, E, D, F) expert weights out of a homogeneous scan's
+    operands where Pallas calls consume them (blocks.moe_reads_stacked).
+    XLA fuses a scan's dynamic slice into its own ops but copies one that
+    feeds a custom call: 3 x E x D x F per layer and step.  Returns (the
+    blocks left to scan, the stacked weights, the layer indices to scan),
+    the last two None where nothing is hoisted; ``_with_layer`` puts them
+    back inside the body."""
+    if not B.moe_reads_stacked(cfg, dispatch_mode):
+        return blocks, None, None
+    moe = dict(blocks["moe"])
+    stacked = {n: moe.pop(n) for n in ("w_gate", "w_up", "w_down")}
+    layers = jnp.arange(stacked["w_gate"].shape[0], dtype=jnp.int32)
+    return {**blocks, "moe": moe}, stacked, layers
+
+
+def _with_layer(p, stacked, layer):
+    """One scan step's block params with the hoisted expert weights (closed
+    over, loop-invariant) and the index of the layer moe_gemm reads."""
+    if stacked is None:
+        return p
+    return {**p, "moe": {**p["moe"], **stacked, "layer": layer}}
+
+
 def _unroll() -> int:
     ctx = current_ctx()
     return max(int(ctx.unroll), 1) if ctx is not None else 1
@@ -211,9 +235,12 @@ def _scan_attn_stack(params, cfg: ModelConfig, x, positions, cache, cache_pos,
     flags = local_flags(cfg)
     is_moe = cfg.is_moe  # scanned stack is homogeneous (prologue handled outside)
     pstack = _placement_stack(cfg, placements)
+    blocks, stacked, layers = _hoist_expert_weights(cfg, params["blocks"],
+                                                    dispatch_mode)
 
     def body(x, xs):
-        p, c, flag, inv = xs
+        p, c, flag, inv, layer = xs
+        p = _with_layer(p, stacked, layer)
         plc = (ExpertPlacement.from_slot_map(inv, cfg.num_experts)
                if inv is not None else None)
         if decode:
@@ -227,7 +254,7 @@ def _scan_attn_stack(params, cfg: ModelConfig, x, positions, cache, cache_pos,
 
     if cfg.remat:
         body = jax.checkpoint(body, policy=_remat_policy(cfg))
-    x, (new_cache, auxs) = jax.lax.scan(body, x, (params["blocks"], cache, flags, pstack),
+    x, (new_cache, auxs) = jax.lax.scan(body, x, (blocks, cache, flags, pstack, layers),
                                         unroll=_unroll())
     return x, new_cache, auxs
 
@@ -536,9 +563,12 @@ def decode_step_paged(params, cfg: ModelConfig, token, pages, block_tables,
     flags = local_flags(cfg)
     is_moe = cfg.is_moe
     pstack = _placement_stack(cfg, placements)
+    blocks, stacked, layers = _hoist_expert_weights(cfg, params["blocks"],
+                                                    dispatch_mode)
 
     def body(x, xs):
-        p, c, flag, inv = xs
+        p, c, flag, inv, layer = xs
+        p = _with_layer(p, stacked, layer)
         plc = (ExpertPlacement.from_slot_map(inv, cfg.num_experts)
                if inv is not None else None)
         x, newc, aux = B.attn_block_decode_paged(
@@ -547,7 +577,7 @@ def decode_step_paged(params, cfg: ModelConfig, token, pages, block_tables,
         return _seq_constraint(x), (newc, aux)
 
     x, (new_pages, auxs) = jax.lax.scan(
-        body, x, (params["blocks"], pages, flags, pstack), unroll=_unroll())
+        body, x, (blocks, pages, flags, pstack, layers), unroll=_unroll())
     aux = _agg_aux(auxs)
 
     x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)

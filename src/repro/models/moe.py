@@ -150,12 +150,15 @@ def _expert_ffn(params: dict, xe: jax.Array) -> jax.Array:
 
 
 def _expert_ffn_kernel(params: dict, xe: jax.Array, interpret: bool) -> jax.Array:
-    """_expert_ffn through three moe_gemm Pallas calls."""
+    """_expert_ffn through three moe_gemm Pallas calls.  Inside a layer scan
+    ``params`` may hold the whole stack's (L, E, ...) expert weights and the
+    scan's ``"layer"`` index (model.py), which moe_gemm reads in place."""
     from repro.kernels.moe_gemm import moe_gemm
-    gate = moe_gemm(xe, params["w_gate"], interpret=interpret)
-    up = moe_gemm(xe, params["w_up"], interpret=interpret)
+    layer = params.get("layer")
+    gate = moe_gemm(xe, params["w_gate"], layer, interpret=interpret)
+    up = moe_gemm(xe, params["w_up"], layer, interpret=interpret)
     act = jax.nn.silu(gate.astype(jnp.float32)).astype(xe.dtype) * up
-    return moe_gemm(act, params["w_down"], interpret=interpret)
+    return moe_gemm(act, params["w_down"], layer, interpret=interpret)
 
 
 def _dispatch_tables(slot_idx: jax.Array, gates: jax.Array, num_slots: int, capacity: int):
@@ -182,7 +185,9 @@ def moe_apply(params: dict, cfg: ModelConfig, x: jax.Array,
     """x: (B, S, d).  Returns (y, aux) where aux carries router losses and,
     when return_stats, per-expert activation counts + per-token expert ids
     (the signals Gimbal's affinity/EPLB collectors consume).  ``interpret``
-    runs the "fused" mode's kernels in the Pallas interpreter (CPU)."""
+    runs the "fused" mode's kernels in the Pallas interpreter (CPU).  Under
+    "fused", ``params`` may carry stacked expert weights and a ``"layer"``
+    index (see _expert_ffn_kernel); the einsum modes take one layer's."""
     b, s, d = x.shape
     t = b * s
     e, k = cfg.num_experts, cfg.moe_top_k

@@ -40,6 +40,33 @@ def test_moe_gemm_block_shapes(block):
                                rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("c", [8, 200])                 # decode, multi-block prefill
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_moe_gemm_stacked_reads_layer_in_place(c, dtype):
+    """The stacked form (w: (L, E, D, F) + layer index) is the per-layer
+    form on ``w[l]``, bit for bit, for every layer of the stack."""
+    k1, k2 = jax.random.split(jax.random.key(c))
+    xe = jax.random.normal(k1, (4, c, 64), dtype)
+    w = jax.random.normal(k2, (3, 4, 64, 256), dtype)
+    for l in range(w.shape[0]):
+        out = moe_gemm(xe, w, jnp.int32(l), interpret=True)
+        want = moe_gemm(xe, w[l], interpret=True)
+        assert out.shape == want.shape and out.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+def test_moe_gemm_stacked_refuses_to_pad():
+    """A stack whose F is not a multiple of the f-block is refused (padding
+    it would copy every layer), as is a stack without its layer index."""
+    xe = jnp.ones((2, 8, 32), jnp.float32)
+    with pytest.raises(ValueError, match="multiple of block_f"):
+        moe_gemm(xe, jnp.ones((3, 2, 32, 200), jnp.float32), jnp.int32(0),
+                 interpret=True)
+    with pytest.raises(ValueError, match="layer index"):
+        moe_gemm(xe, jnp.ones((3, 2, 32, 128), jnp.float32), interpret=True)
+
+
 @pytest.mark.parametrize("b,hq,hkv,s,d", [(2, 4, 4, 64, 16), (3, 8, 2, 300, 32),
                                           (1, 16, 1, 1024, 64), (4, 8, 8, 128, 128)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -1,6 +1,6 @@
 """Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e at
 qwen3-30b-a3b widths (d_model 2048, 32 q / 4 kv heads x 128, 128 experts
-top-8, expert d_ff 768, 16-token KV pages).
+top-8, expert d_ff 768, 16-token KV pages, 4 layers).
 
 Nothing runs: each kernel is lowered and compiled by the TPU compiler for a
 described (not attached) v5e chip, so a block that breaks the tiling rules,
@@ -27,6 +27,7 @@ from repro.models.moe import ExpertPlacement
 
 D_MODEL, HQ, HKV, HEAD, E, TOPK, D_FF = 2048, 32, 4, 128, 128, 8, 768
 PAGE, MAX_SEQ, SLOTS = 16, 2048, 8
+LAYERS = 4
 NB = MAX_SEQ // PAGE
 POOL = SLOTS * NB + 1                          # + the reserved garbage page
 
@@ -97,3 +98,14 @@ def test_moe_gemm_compiles(one_chip, capacity, proj):
     d_in, d_out = (D_MODEL, D_FF) if proj == "up" else (D_FF, D_MODEL)
     _compile(moe_gemm, one_chip, ((E, capacity, d_in), jnp.bfloat16),
              ((E, d_in, d_out), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("capacity", [40, 1032])         # decode, prefill
+@pytest.mark.parametrize("proj", ["up", "down"])
+def test_moe_gemm_stacked_compiles(one_chip, capacity, proj):
+    """The stacked form a 4-layer scan hands the kernel: the weights of
+    every layer and the scan's layer index, read in place."""
+    d_in, d_out = (D_MODEL, D_FF) if proj == "up" else (D_FF, D_MODEL)
+    _compile(moe_gemm, one_chip, ((E, capacity, d_in), jnp.bfloat16),
+             ((LAYERS, E, d_in, d_out), jnp.bfloat16), ((), jnp.int32))
+
