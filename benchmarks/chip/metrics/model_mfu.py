@@ -1,7 +1,7 @@
 """Model FLOP/s utilisation of the traced window, %: the model FLOPs of the
 prompt tokens prefilled and the tokens decoded in it, at their true lengths
-(work.model_prefill / work.model_decode), over the window times the chip's
-bf16 peak."""
+(counted by the arch module, work.model_flops), over the window times the
+chip's bf16 peak."""
 from benchmarks.chip import work
 
 
@@ -10,10 +10,9 @@ def read(run):
     if t is None or t.window_s <= 0:
         return None
     a, b = run.trace_host
-    flops = sum(work.model_prefill(run.config, s.rows)
-                for s in run.rec.prefills if a <= s.t0 and s.t1 <= b)
-    flops += sum(work.model_decode(run.config, s.lengths)
-                 for s in run.rec.decodes if a <= s.t0 and s.t1 <= b)
+    flops = sum(work.model_flops(run.config, s)
+                for s in run.rec.prefills + run.rec.decodes
+                if a <= s.t0 and s.t1 <= b)
     if flops <= 0:
         return None
     return 100.0 * flops / (t.window_s * run.peaks["bf16_flops"])

@@ -1,10 +1,11 @@
 """moe_gemm's share of its roofline, %: the least time the chip needs for
-the expert GEMMs of the traced prefills and decode steps (T*k routed rows,
-the weights of the experts that received a token, no capacity padding) over
-the kernel's device time in the trace.  Decode steps hand back their routed
-expert ids, so their count of experts hit is exact; the program's prefill
-returns none, so a prefill counts the experts a uniform router would hit on
-average, E * (1 - (1 - k/E)^T) per layer."""
+the expert GEMMs of the traced prefills and decode steps over the kernel's
+device time in the trace.  The arch module counts each call's work: for
+every expert on the chip (archs/gqa_stack.py), T*k routed rows and the
+weights of the experts that received a token, no capacity padding.  Decode
+steps hand back their routed expert ids, so their count of experts hit is
+exact; the program's prefill returns none, so a prefill counts the experts
+a uniform router would hit on average, E * (1 - (1 - k/E)^T) per layer."""
 from benchmarks.chip import work
 
 KERNEL = "moe_gemm"
@@ -12,7 +13,7 @@ KERNEL = "moe_gemm"
 
 def read(run):
     t = run.trace
-    if t is None or not run.config.get("num_experts"):
+    if t is None or not work.held_experts(run.config):
         return None
     spent = t.kernel_s(KERNEL)
     a, b = run.trace_host
@@ -20,10 +21,9 @@ def read(run):
     for s in run.rec.prefills + run.rec.decodes:
         if not (a <= s.t0 and s.t1 <= b):
             continue
-        hit = (work.distinct_experts(s.experts) if s.experts is not None
-               else work.expected_experts(run.config, s.rows))
-        least += work.least_seconds(*work.moe_gemm(run.config, s.rows, hit),
-                                    run.peaks)
+        c = work.kernel_work(run.config, KERNEL, s)
+        if c is not None:
+            least += work.least_seconds(*c, run.peaks)
     if spent <= 0 or least <= 0:
         return None
     return 100.0 * least / spent

@@ -6,7 +6,9 @@ The run needs a TPU and the chips the cell names: with neither it exits 2
 and prints no result.  It keeps JAX's compile cache where
 ``$JAX_COMPILATION_CACHE_DIR`` says, else in ``.jax_cache/`` of the checkout,
 and the profiler's trace (``--trace 1``) in ``.chipbench/`` of the checkout,
-deleted once read.
+deleted once read.  With ``--trace 1`` the program's own recorder
+(``repro.core.trace``) is on from the lead-in to the close, for the
+metrics that read its records; ``--trace 0`` leaves it off.
 
 Set-up (``setup_s``, from process start to the window's opening): weights
 drawn from the seed on the device, the cluster built, every shape of this
@@ -73,7 +75,9 @@ class CompileClock:
 
 @dataclasses.dataclass
 class Run:
-    """What a metric reader sees."""
+    """What a metric reader sees.  ``spans``: the program's own records
+    (``repro.core.trace``) of the whole drive in a traced run, empty in an
+    untraced one; spans.py reduces them."""
     cell: spec.Cell
     config: dict
     seconds: float
@@ -85,6 +89,7 @@ class Run:
     trace: object = None          # devtrace.Trace of the traced part
     trace_host: tuple = ()        # (start, stop) of the trace, host clock
     peaks: Optional[dict] = None
+    spans: list = dataclasses.field(default_factory=list)
 
 
 def use_compile_cache(root: Path) -> str:
@@ -116,7 +121,8 @@ def _log(msg: str) -> None:
 def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             devices, *, tamper: Optional[Callable] = None,
             controls: Optional[tuple] = None, rate: Optional[float] = None,
-            keep_gaps: bool = False) -> dict:
+            keep_gaps: bool = False,
+            on_run: Optional[Callable[[Run], None]] = None) -> dict:
     """One run on ``devices`` (platform unchecked: ``main`` checks it).
     ``tamper(cluster)``, for the tests, may break the timed path after
     warm-up.  The tools may offer another ``rate`` than the cell's
@@ -124,11 +130,16 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     same sample (``controls``, tools/calibrate.py): the gap statistics of the
     program's tokens and of each control's come back under ``"gap_stats"``,
     and with ``keep_gaps`` the gaps and margins themselves under
-    ``"gap_arrays"``.  Returns the result line as a dict, with ``diag``
+    ``"gap_arrays"``.  ``on_run`` is handed the ``Run`` before its metrics
+    are read (tools/spans.py).  With ``trace`` the program's own recorder
+    (``repro.core.trace``) is on through ``serve.drive`` and its records
+    go to ``Run.spans``; without, it stays off, so no end-to-end metric is
+    taken with it on.  Returns the result line as a dict, with ``diag``
     (rate, backlog at the close, mean decode batch, tails, the window's
     stalls) beside the metrics."""
     import jax
     import numpy as np
+    from repro.core import trace as tracer
     from repro.models.config import ModelConfig
 
     from benchmarks.chip import devtrace, serve, weights, work
@@ -136,7 +147,7 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     clock = CompileClock()
     device = devices[0]
     config, cp = cell.config, cell.params
-    cfg = ModelConfig(**spec.model_fields(config))
+    cfg = ModelConfig(**spec.arch_module(config).model_fields(config))
     t = time.perf_counter()
     params = weights.make(config, seed, device)
     jax.block_until_ready(params)
@@ -174,7 +185,13 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             prof["off"] = time.perf_counter()
             jax.profiler.stop_trace()
 
-    tracks = serve.drive(cluster, arrivals, t0, close, rec, base, on_step)
+    if trace:
+        tracer.enable()
+    try:
+        tracks = serve.drive(cluster, arrivals, t0, close, rec, base, on_step)
+    finally:
+        tracer.disable()
+    records = tracer.drain()
     if prof["on"] is not None and prof["off"] is None:
         prof["off"] = time.perf_counter()
         jax.profiler.stop_trace()
@@ -222,7 +239,8 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     for st in stalls:
         _log("[stall] " + " ".join(f"{k}={v}" for k, v in st.items()))
 
-    run = Run(cell, config, seconds, open_, close, setup_s, tracks, rec)
+    run = Run(cell, config, seconds, open_, close, setup_s, tracks, rec,
+              spans=records)
     device_info = {"platform": device.platform, "kind": device.device_kind,
                    "count": len(devices), "memory_peak_bytes": mem}
     breakdown = None
@@ -237,6 +255,8 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             breakdown = {"device_ops": run.trace.top_ops(10),
                          "idle_gaps": run.trace.idle_gaps(10)}
 
+    if on_run is not None:
+        on_run(run)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         v = spec.metric_reader(m["name"]).read(run)
@@ -246,7 +266,7 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     # ------------------------------------------------------------ correctness
     t = time.perf_counter()
     ref = spec.reference_module(config)
-    moe = bool(config.get("num_experts"))
+    moe = work.held_experts(config) > 0
     picks = check.sample(served, seed, int(cp["sample_requests"]), moe)
     readings = {"compared_tokens": float(sum(len(s.tokens) for s in picks))}
     if moe:

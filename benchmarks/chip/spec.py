@@ -2,25 +2,51 @@
 root, and the files of one cell under this directory.
 
     configs/<config>.json    the model as it is run (published keys, cuts,
-                             serving geometry, the reference module's name)
+                             serving geometry, the names of its arch and
+                             reference modules)
     traffic/<traffic>.json   parameters of the general generator (traffic.py)
     cells/<workload>.json    what belongs to one cell: its offered rate, the
                              lead-in before the window, the correctness sample
                              and each compared number's limit
     metrics/<metric>.py      one reader per metric: ``read(run) -> float|None``
+                             (run.Run: the window, the harness's Recorder, the
+                             device trace, and in traced runs the program's
+                             own span records, ``run.spans``)
     references/<name>.py     a plain reference: ``logits_at(...)`` (check.py)
+    archs/<name>.py          what the harness knows of one architecture,
+                             named by the configuration's ``"arch"``
 
-A new cell, configuration, traffic mix or metric is a new file plus its
-entry in ``BENCHMARK.json``; nothing here changes.
+An arch module provides, each taking the configuration dict:
+
+    model_fields(config)        the program's ModelConfig keyword arguments
+    tree(key, config)           the seeded weights in the program's layout
+                                (weights.py compiles it into one program)
+    held_experts(config)        routed experts of a layer on the chip (0: dense)
+    model_flops(config, call)   model FLOPs of one recorded prefill or decode
+                                step (model_mfu)
+    kernel_work(config, kernel, call)
+                                (flops, bytes) of the named kernel in that
+                                call, or None where the call runs no such
+                                kernel (the kernels' rooflines)
+
+A ``call`` is what serve.Recorder keeps of one backend call (serve.Span):
+``rows`` (a prefill's prompt length, a decode step's active rows),
+``lengths`` (a decode step's resident tokens per row, None for a prefill)
+and ``experts`` (routed expert ids, where the program hands them back).
+
+A new cell, configuration, architecture, traffic mix or metric is a new
+file plus its entry in ``BENCHMARK.json``; nothing here, in weights.py,
+work.py or run.py changes.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
 from types import ModuleType
-from typing import Dict, List, Optional
+from typing import List
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]            # the checkout root (holds BENCHMARK.json)
@@ -85,60 +111,12 @@ def reference_module(config: dict, here: Path = HERE) -> ModuleType:
                        "chipbench_reference_")
 
 
-# --------------------------------------------------------------- the model
-# Published config.json keys -> the program's ModelConfig fields.  Keys the
-# program has no field for (Granite's multipliers, Qwen3's q/k norm) are
-# listed as departures in the configuration file itself.
-_FIELDS = {
-    "num_hidden_layers": "num_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "rms_norm_eps": "norm_eps",
-    "rope_theta": "rope_theta",
-    "tie_word_embeddings": "tie_embeddings",
-    "num_experts": "num_experts",
-    "num_experts_per_tok": "moe_top_k",
-    "moe_intermediate_size": "moe_d_ff",
-    "torch_dtype": "dtype",
-}
+@functools.lru_cache(maxsize=None)
+def _arch(path: Path) -> ModuleType:
+    return load_module(path, "chipbench_arch_")
 
 
-_ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4}
-
-
-def dims(config: dict) -> Dict[str, int]:
-    """The sizes the harness computes with, from a configuration file:
-    layers L, width d, heads hq / hkv of hd, vocab V, dense width F,
-    experts E (0: dense) with top-k k and width f, bytes b per element."""
-    d, hq = config["hidden_size"], config["num_attention_heads"]
-    return {"L": config["num_hidden_layers"], "d": d, "hq": hq,
-            "hkv": config["num_key_value_heads"],
-            "hd": config.get("head_dim", d // hq), "V": config["vocab_size"],
-            "F": config["intermediate_size"],
-            "E": config.get("num_experts", 0),
-            "k": config.get("num_experts_per_tok", 0),
-            "f": config.get("moe_intermediate_size", 0),
-            "b": _ITEMSIZE[config["torch_dtype"]]}
-
-
-def model_fields(config: dict) -> Dict[str, object]:
-    """ModelConfig keyword arguments for a configuration file: the published
-    keys it holds, mapped by ``_FIELDS``, plus ``capacity_factor`` from the
-    serving group.  ``head_dim`` defaults to hidden_size / heads as in the
-    published models that omit it."""
-    kw: Dict[str, object] = {"name": config["name"],
-                             "family": "moe" if config.get("num_experts") else "dense",
-                             "attention_type": "gqa"}
-    for key, field in _FIELDS.items():
-        if key in config:
-            kw[field] = config[key]
-    if "head_dim" not in kw:
-        kw["head_dim"] = config["hidden_size"] // config["num_attention_heads"]
-    cf: Optional[float] = config["serving"].get("capacity_factor")
-    if cf is not None:
-        kw["capacity_factor"] = float(cf)
-    return kw
+def arch_module(config: dict, here: Path = HERE) -> ModuleType:
+    """The arch module the configuration names, loaded once per path (the
+    work counts ask it for every recorded call)."""
+    return _arch(here / "archs" / f"{config['arch']}.py")

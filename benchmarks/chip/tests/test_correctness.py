@@ -21,7 +21,8 @@ import pytest
 
 from benchmarks.chip import check, faults, run, spec
 
-TINY = {"name": "tiny-moe", "reference": "gqa_stack", "hidden_size": 128,
+TINY = {"name": "tiny-moe", "arch": "gqa_stack", "reference": "gqa_stack",
+        "hidden_size": 128,
         "intermediate_size": 256, "head_dim": 32, "num_attention_heads": 4,
         "num_key_value_heads": 2, "num_hidden_layers": 2, "vocab_size": 256,
         "num_experts": 8, "num_experts_per_tok": 2, "moe_intermediate_size": 64,

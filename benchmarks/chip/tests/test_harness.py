@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from benchmarks.chip import devtrace, spec, stats, traffic, work
-from benchmarks.chip.serve import Track
+from benchmarks.chip.serve import Span, Track
 from benchmarks.chip.traffic import Arrival
 
 HERE = Path(__file__).resolve().parents[1]
@@ -144,26 +144,43 @@ QWEN = spec.load_json(HERE / "configs" / "qwen3-30b-a3b-d4.json")
 GRANITE = spec.load_json(HERE / "configs" / "granite-3-8b-d10.json")
 
 
+def _decode(lengths, experts=None):
+    return Span(0.0, 1.0, len(lengths), lengths=np.asarray(lengths, np.int64),
+                experts=experts)
+
+
 def test_flash_decode_work_counts_valid_kv_only():
     # qwen3-d4: 4 layers, 32 q heads, 4 kv heads, head_dim 128, bf16.
     # rows with 9 and 99 resident tokens attend 10 + 100 = 110 tokens.
-    flops, nbytes = work.flash_decode(QWEN, [9, 99])
+    flops, nbytes = work.kernel_work(QWEN, "flash_decode", _decode([9, 99]))
     assert flops == 4 * (4 * 32 * 128 * 110)
     assert nbytes == 4 * (2 * 4 * 128 * 2 * 110 + 2 * 2 * 32 * 128 * 2)
+    assert work.kernel_work(QWEN, "flash_decode", Span(0.0, 1.0, 7)) is None
 
 
 def test_moe_gemm_work_counts_routed_rows_and_hit_experts():
-    # 10 tokens, top-8: 80 rows per layer; d 2048, f 768; 300 experts hit
-    flops, nbytes = work.moe_gemm(QWEN, 10, 300)
+    # 10 tokens, top-8: 80 rows per layer; d 2048, f 768; 300 experts hit,
+    # 75 in each of the 4 layers (80 routed ids, 5 of them repeats)
+    ids = np.stack([np.concatenate([np.arange(75), np.arange(5)]) + l
+                    for l in range(4)]).reshape(4, 10, 8)
+    flops, nbytes = work.kernel_work(QWEN, "moe_gemm",
+                                     Span(0.0, 1.0, 10, experts=ids))
     assert flops == 4 * 3 * 2 * 80 * 2048 * 768
     assert nbytes == 300 * 3 * 2048 * 768 * 2 + 4 * 80 * 3 * (2048 + 768) * 2
+    assert work.kernel_work(GRANITE, "moe_gemm", Span(0.0, 1.0, 10)) is None
+    assert work.kernel_work(QWEN, "absent", Span(0.0, 1.0, 10)) is None
 
 
 def test_distinct_and_expected_experts():
     ids = np.array([[[1, 2], [2, 3]], [[5, 5], [5, 6]]])     # (L=2, T=2, k=2)
     assert work.distinct_experts(ids) == 3 + 2
-    assert work.expected_experts(QWEN, 1) == pytest.approx(4 * 8)
-    assert work.expected_experts(QWEN, 10_000) == pytest.approx(4 * 128)
+    arch = spec.arch_module(QWEN)
+    assert arch.expected_experts(QWEN, 1) == pytest.approx(4 * 8)
+    assert arch.expected_experts(QWEN, 10_000) == pytest.approx(4 * 128)
+    # a prefill hands back no ids: its count of hit experts is expected
+    assert work.kernel_work(QWEN, "moe_gemm", Span(0.0, 1.0, 1)) == \
+        arch.moe_gemm(QWEN, 1, arch.expected_experts(QWEN, 1))
+    assert (work.held_experts(QWEN), work.held_experts(GRANITE)) == (128, 0)
 
 
 def test_model_flops_by_hand():
@@ -171,13 +188,13 @@ def test_model_flops_by_hand():
     # 2*4096*(2*32 + 2*8)*128 and a 3-matrix SwiGLU 2*3*4096*12800
     per = 2 * 4096 * 80 * 128 + 2 * 3 * 4096 * 12800
     head = 2 * 4096 * 49155
-    assert work.model_prefill(GRANITE, 3) == \
+    assert work.model_flops(GRANITE, Span(0.0, 1.0, 3)) == \
         10 * (3 * per + 4 * 32 * 128 * 6) + head
-    assert work.model_decode(GRANITE, [4, 0]) == \
+    assert work.model_flops(GRANITE, _decode([4, 0])) == \
         10 * (2 * per + 4 * 32 * 128 * 6) + 2 * head
     # qwen3-d4: router 2*2048*128 and 8 experts 2*3*2048*768 per token
     per_q = 2 * 2048 * 72 * 128 + 2 * 2048 * 128 + 8 * 2 * 3 * 2048 * 768
-    assert work.model_decode(QWEN, [0]) == 4 * (per_q + 4 * 32 * 128) \
+    assert work.model_flops(QWEN, _decode([0])) == 4 * (per_q + 4 * 32 * 128) \
         + 2 * 2048 * 151936
 
 
@@ -322,13 +339,13 @@ def test_every_metric_of_the_benchmark_has_a_reader():
 
 def test_config_maps_to_the_program_config():
     from repro.models.config import ModelConfig
-    cfg = ModelConfig(**spec.model_fields(QWEN))
+    cfg = ModelConfig(**spec.arch_module(QWEN).model_fields(QWEN))
     assert (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
             cfg.head_dim, cfg.num_experts, cfg.moe_top_k, cfg.moe_d_ff,
             cfg.vocab_size, cfg.dtype) == (4, 2048, 32, 4, 128, 128, 8, 768,
                                            151936, "bfloat16")
     assert cfg.capacity_factor * cfg.moe_top_k >= cfg.num_experts  # dropless
-    g = ModelConfig(**spec.model_fields(GRANITE))
+    g = ModelConfig(**spec.arch_module(GRANITE).model_fields(GRANITE))
     assert (g.num_layers, g.d_model, g.head_dim, g.d_ff, g.tie_embeddings,
             g.norm_eps, g.is_moe) == (10, 4096, 128, 12800, True, 1e-5, False)
 
@@ -340,10 +357,254 @@ def test_weights_take_the_programs_layout():
     from benchmarks.chip import weights
     for config in (QWEN, GRANITE):
         ours = weights.shapes(config)
-        theirs = M.abstract_params(ModelConfig(**spec.model_fields(config)))
+        theirs = M.abstract_params(
+            ModelConfig(**spec.arch_module(config).model_fields(config)))
         assert jax.tree.structure(ours) == jax.tree.structure(theirs)
         for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
             assert (a.shape, a.dtype) == (b.shape, b.dtype)
+
+
+# Digests of the weights the harness drew for these tiny configurations
+# before their architecture moved into archs/gqa_stack.py: the same seed
+# must keep giving the same leaves.
+WEIGHT_DIGESTS = {
+    ("moe", 0): "f1e21af55121e62db9c078954ef915af7f882bf0abd3dbbac6dbbc87d2fc261e",
+    ("moe", 2**40 + 7): "196febaa7ed94e368d96dd6ce6554914929c419d26321d12835d33b08d8a6de1",
+    ("dense", 0): "d8eefbd4d5fbf827e4efd0e086ca290051b9ec31d0cd3fb5478ede6bf782b168",
+    ("dense", 2**40 + 7): "b19694d9515c82b0eb3dce1ec462f101a814a79f097af8c13da881935ac67760",
+}
+
+
+@pytest.mark.parametrize("kind,seed", sorted(WEIGHT_DIGESTS))
+def test_same_seed_same_weights(kind, seed):
+    import hashlib
+
+    import jax
+    from benchmarks.chip import weights
+    from benchmarks.chip.tests.test_correctness import TINY, TINY_DENSE
+    h = hashlib.sha256()
+    tree = weights.make(TINY if kind == "moe" else TINY_DENSE, seed)
+    for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+        a = np.asarray(leaf)
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(str(a.dtype).encode())
+        h.update(a.tobytes())
+    assert h.hexdigest() == WEIGHT_DIGESTS[kind, seed]
+
+
+# ------------------------------------------------ an architecture as new files
+# A latent-attention mixture of experts in DeepSeek-V2's keys, as a later
+# configuration would bring it: its own key names, one dense leading layer,
+# shared experts, and a chip that holds a share of the routed experts.
+TOY_ARCH = r"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from benchmarks.chip import weights
+
+B = 2
+
+
+def _z(c):
+    return (c["hidden_size"], c["num_attention_heads"], c["q_lora_rank"],
+            c["kv_lora_rank"], c["qk_nope_head_dim"], c["qk_rope_head_dim"],
+            c["v_head_dim"])
+
+
+def model_fields(c):
+    return {"name": c["name"], "family": "moe", "attention_type": "mla",
+            "num_layers": c["num_hidden_layers"], "d_model": c["hidden_size"],
+            "num_heads": c["num_attention_heads"],
+            "num_kv_heads": c["num_attention_heads"],
+            "q_lora_rank": c["q_lora_rank"], "kv_lora_rank": c["kv_lora_rank"],
+            "qk_nope_head_dim": c["qk_nope_head_dim"],
+            "qk_rope_head_dim": c["qk_rope_head_dim"],
+            "v_head_dim": c["v_head_dim"], "d_ff": c["intermediate_size"],
+            "vocab_size": c["vocab_size"], "num_experts": c["n_routed_experts"],
+            "num_shared_experts": c["n_shared_experts"],
+            "moe_top_k": c["num_experts_per_tok"],
+            "moe_d_ff": c["moe_intermediate_size"],
+            "first_k_dense": c["first_k_dense_replace"],
+            "dtype": c["torch_dtype"]}
+
+
+def held_experts(c):
+    return c["serving"]["held_experts"]
+
+
+def tree(key, c):
+    d, h, rq, rkv, dn, dr, dv = _z(c)
+    n_pro = c["first_k_dense_replace"]
+    L, E = c["num_hidden_layers"] - n_pro, c["n_routed_experts"]
+    f, F = c["moe_intermediate_size"], c["intermediate_size"]
+    dt = jnp.dtype(c["torch_dtype"])
+    ks = iter(jax.random.split(key, 64))
+    st = lambda n, shape: weights.stacked(next(ks), n, shape, 0.02, dt)
+    norm = lambda n, w: weights.stacked(next(ks), n, (w,), weights.NORM_STD, dt)
+
+    def block(n, ffn):
+        return {"attn_norm": {"scale": norm(n, d)},
+                "attn": {"wkv_a": st(n, (d, rkv + dr)), "kv_norm": norm(n, rkv),
+                         "wkv_b": st(n, (rkv, h, dn + dv)),
+                         "wo": st(n, (h, dv, d)), "wq_a": st(n, (d, rq)),
+                         "q_norm": norm(n, rq), "wq_b": st(n, (rq, h, dn + dr))},
+                "ffn_norm": {"scale": norm(n, d)}, **ffn}
+
+    pro = block(n_pro, {"ffn": {"w_gate": st(n_pro, (d, F)),
+                                "w_up": st(n_pro, (d, F)),
+                                "w_down": st(n_pro, (F, d))}})
+    g = f * c["n_shared_experts"]
+    moe = {"w_router": weights.stacked(next(ks), L, (d, E), 0.02, jnp.float32),
+           "w_gate": st(L, (E, d, f)), "w_up": st(L, (E, d, f)),
+           "w_down": st(L, (E, f, d)),
+           "shared": {"w_gate": st(L, (d, g)), "w_up": st(L, (d, g)),
+                      "w_down": st(L, (g, d))}}
+    return {"embed": {"embedding": st(1, (c["vocab_size"], d))[0],
+                      "unembedding": st(1, (c["vocab_size"], d))[0]},
+            "final_norm": {"scale": norm(1, d)[0]},
+            "prologue": [jax.tree.map(lambda x: x[i], pro) for i in range(n_pro)],
+            "blocks": block(L, {"moe": moe})}
+
+
+def model_flops(c, call):
+    return 1000 * (call.rows if call.lengths is None else len(call.lengths))
+
+
+def kernel_work(c, kernel, call):
+    d, h, rq, rkv, dn, dr, dv = _z(c)
+    if kernel == "flash_decode" and call.lengths is not None:
+        toks = int((np.asarray(call.lengths) + 1).sum())
+        return 0, toks * (rkv + dr) * B         # latents, not K/V
+    if kernel == "moe_gemm" and call.experts is not None:
+        rows = int((np.asarray(call.experts) < held_experts(c)).sum())
+        return rows * 6 * d * c["moe_intermediate_size"], 0
+    return None
+"""
+
+TOY_CONFIG = {
+    "name": "toy-latent", "arch": "latent_moe", "reference": "latent_moe",
+    "hidden_size": 64, "num_attention_heads": 4, "q_lora_rank": 32,
+    "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+    "v_head_dim": 16, "intermediate_size": 96, "moe_intermediate_size": 24,
+    "n_routed_experts": 8, "n_shared_experts": 2, "num_experts_per_tok": 2,
+    "first_k_dense_replace": 1, "num_hidden_layers": 3, "vocab_size": 128,
+    "torch_dtype": "bfloat16",
+    "serving": {"max_slots": 4, "max_seq": 64, "held_experts": 4}}
+
+TOY_METRIC = '''"""Prompt tokens the window's prefills took, from the program's records."""
+
+
+def read(run):
+    n = sum(r.attrs["plen"] for r in run.spans
+            if r.name == "repro:backend.prefill"
+            and run.open * 1e9 <= r.start < run.close * 1e9)
+    return float(n) if n else None
+'''
+
+# Runs in the copy: what a cell of the toy architecture asks of the harness.
+TOY_RUN = r"""
+import json, types
+import jax
+import numpy as np
+from repro.core.trace import Record
+from repro.models import model as M
+from repro.models.config import ModelConfig
+from benchmarks.chip import run, spec, weights, work
+from benchmarks.chip.serve import Span
+
+cell = spec.load_cell("toy-chat")
+config = cell.config
+cfg = ModelConfig(**spec.arch_module(config).model_fields(config))
+ours, theirs = weights.shapes(config), M.abstract_params(cfg)
+same = (jax.tree.structure(ours) == jax.tree.structure(theirs) and all(
+    (a.shape, a.dtype) == (b.shape, b.dtype)
+    for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs))))
+ids = np.array([[[0, 5], [3, 7]], [[4, 1], [2, 6]]])    # (L=2, T=2, k=2)
+pre = Span(100.5, 100.6, 12, experts=ids)
+dec = Span(101.0, 101.1, 2, lengths=np.array([9, 19]), experts=ids)
+s = 1_000_000_000
+recs = [Record("repro:backend.prefill", 100 * s + 1, 100 * s + 9,
+               attrs={"plen": 12, "bucket": 16}),
+        Record("repro:backend.prefill", 103 * s, 103 * s + 5,
+               attrs={"plen": 20, "bucket": 32}),
+        Record("repro:backend.prefill", 111 * s, 111 * s + 5,
+               attrs={"plen": 7, "bucket": 16})]
+trace = types.SimpleNamespace(window_s=1.0, kernel_s=lambda k: 1.0)
+ran = run.Run(cell, config, 10.0, 100.0, 110.0, 1.0, [],
+              types.SimpleNamespace(prefills=[pre], decodes=[dec]),
+              trace=trace, trace_host=(100.0, 102.0),
+              peaks={"bf16_flops": 1e6, "hbm_bytes_s": 1e4}, spans=recs)
+read = {k: spec.metric_reader(k).read(ran) for k in (
+    "prompt_tokens", "prefill_pad_share", "expert_host_ms", "model_mfu",
+    "moe_gemm_roofline", "flash_decode_roofline")}
+print(json.dumps({
+    "fields": [cfg.attention_type, cfg.first_k_dense, cfg.num_shared_experts,
+               cfg.num_experts, cfg.moe_top_k],
+    "same_layout": same, "held": work.held_experts(config),
+    "moe_gemm": work.kernel_work(config, "moe_gemm", dec),
+    "flash_decode": work.kernel_work(config, "flash_decode", dec),
+    "reference": callable(spec.reference_module(config).logits_at),
+    "per_layer": [m["name"] for m in cell.per_layer], "read": read}))
+"""
+
+CORE = ("spec.py", "weights.py", "work.py", "run.py")
+
+
+def test_an_architecture_and_its_metrics_are_new_files_only(tmp_path):
+    """A toy architecture, its configuration, reference, cell and a metric
+    on the program's spans, written into a copy of the harness beside its
+    checkout's files, reach the program's layout, the work counts and the
+    metric readers with the core files unedited."""
+    import filecmp
+    import subprocess
+    import sys
+    here = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(HERE, here, ignore=shutil.ignore_patterns("__pycache__"))
+    (here / "archs" / "latent_moe.py").write_text(TOY_ARCH)
+    (here / "configs" / "toy-latent.json").write_text(json.dumps(TOY_CONFIG))
+    (here / "references" / "latent_moe.py").write_text(
+        '"""Stands for the plain reference of the toy."""\n\n\n'
+        "def logits_at(*args, **kw):\n    raise NotImplementedError\n")
+    (here / "cells" / "toy-chat.json").write_text(json.dumps(
+        {"rate_rps": 1.0, "lead_in_s": 1.0, "sample_requests": 2, "limits": {}}))
+    (here / "metrics" / "prompt_tokens.py").write_text(TOY_METRIC)
+    bench = spec.load_json(spec.ROOT / "BENCHMARK.json")
+    bench["configs"].append({"name": "toy-latent", "file": "x", "source": "x",
+                             "reduced": [], "why": "x"})
+    bench["workloads"].append({"name": "toy-chat", "config": "toy-latent",
+                               "traffic": "burstgpt-descending", "chips": 1,
+                               "why": "x"})
+    bench["per_layer"].append({"name": "prompt_tokens", "unit": "tokens",
+                               "better": "higher", "source": "program_counter",
+                               "layer": "x", "moves": "ttft_p50_ms",
+                               "workloads": ["toy-chat"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    p = subprocess.run(
+        [sys.executable, "-c", TOY_RUN], cwd=tmp_path, capture_output=True,
+        text=True, timeout=300,
+        env={"PATH": "/usr/bin:/bin", "HOME": str(tmp_path),
+             "PYTHONPATH": str(spec.ROOT / "src"), "JAX_PLATFORMS": "cpu"})
+    assert p.returncode == 0, p.stderr[-4000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["fields"] == ["mla", 1, 2, 8, 2]
+    assert out["same_layout"] and out["held"] == 4 and out["reference"]
+    # 4 of the 8 routed ids land on the 4 held experts: 4 rows of 6*64*24
+    assert out["moe_gemm"] == [4 * 6 * 64 * 24, 0]
+    assert out["flash_decode"] == [0, (10 + 20) * (16 + 8) * 2]
+    assert "prompt_tokens" in out["per_layer"]
+    assert "expert_host_ms" not in out["per_layer"]    # qwen3-chat's alone
+    read = out["read"]
+    assert read["prompt_tokens"] == 12 + 20                  # 111 s: after
+    assert read["prefill_pad_share"] == pytest.approx(100 * (4 + 12) / 48)
+    assert read["expert_host_ms"] is None               # no expert records
+    assert read["model_mfu"] == pytest.approx(100 * (1000 * 12 + 1000 * 2) / 1e6)
+    # the prefill and the decode step each route 4 rows to held experts
+    assert read["moe_gemm_roofline"] == pytest.approx(
+        100 * 2 * (4 * 6 * 64 * 24 / 1e6))
+    assert read["flash_decode_roofline"] == pytest.approx(
+        100 * (30 * 24 * 2 / 1e4))
+    assert all(filecmp.cmp(here / f, HERE / f, shallow=False) for f in CORE)
 
 
 # ------------------------------------------------------------- chip required

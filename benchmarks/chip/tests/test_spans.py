@@ -6,6 +6,7 @@ recorder on (tools/spans.py), whole, at a size the CPU holds.
 """
 from __future__ import annotations
 
+import dataclasses
 import types
 
 import jax
@@ -214,3 +215,22 @@ def test_with_the_recorder_off_the_program_reads_nothing():
     assert all(v is None for v in out["program"].values())
     assert out["ttft_parts_ms"] == {"n": 0}
     assert out["harness"]["decode_step_ms"] is not None
+
+
+def test_a_traced_run_records_the_program_for_its_metrics(monkeypatch):
+    """``--trace 1``: run.measure turns the recorder on through the drive
+    (no stand-in) and the metrics that read ``Run.spans`` are reported.
+    The CPU gets a row of peaks, since a traced run asks for them (its
+    trace holds no TPU, so no device metric reads them)."""
+    from benchmarks.chip import work
+    monkeypatch.setitem(work.PEAKS, jax.devices()[0].device_kind,
+                        work.PEAKS["TPU v5 lite"])
+    cell = dataclasses.replace(_cell("moe"), per_layer=[
+        {"name": "expert_host_ms", "unit": "ms"},
+        {"name": "prefill_pad_share", "unit": "%"}])
+    out = tool.measure(cell, 5, 3.0, True, jax.devices())
+    assert out["correct"]
+    assert out["metrics"] == {k: out["program"][k] for k in
+                              ("expert_host_ms", "prefill_pad_share")}
+    assert all(v is not None for v in out["metrics"].values())
+    assert [op for op, _, _ in out["profiler"]] == ["start", "stop"]

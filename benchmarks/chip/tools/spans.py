@@ -12,13 +12,14 @@ recorder's cost on ``prefill_ms`` and ``decode_step_ms``.
     python3 -m benchmarks.chip.tools.spans --workload qwen3-chat \\
         --seeds 11 12 13 --seconds 51 --trace 1 --out chiprun_out/spans.jsonl
 
-Each run is ``run.measure`` with three stand-ins, in one process so the
-compiles are paid once: ``serve.drive`` turns the recorder on as it starts,
-which is where set-up ends, and drains it when it returns;
-``devtrace.load`` reduces the trace through ``spans.reduce``; the
-profiler's start and stop are timed.  Prints one
-JSON line per run (and appends it to ``--out``).  Not part of a benchmark
-run.
+Each run is ``run.measure``, in one process so the compiles are paid
+once, which hands back its ``Run`` and, in a traced run, the program's
+records in ``Run.spans``.  Stand-ins: ``devtrace.load`` reduces the trace
+through ``spans.reduce``; the profiler's start and stop are timed; and
+where the recorder is asked on in an untraced run (``--trace 0``), or off
+in a traced one (``--recorder 0``), ``serve.drive`` turns it so as it
+starts, which is where set-up ends.  Prints one JSON line per run (and
+appends it to ``--out``).  Not part of a benchmark run.
 """
 from __future__ import annotations
 
@@ -27,10 +28,20 @@ import json
 import os
 import sys
 import time
-import types
 from pathlib import Path
 
 from benchmarks.chip import devtrace, run, serve, spans, spec, stats
+
+
+def _recorder(on: bool, drive):
+    """``drive`` (``serve.drive``) with the program's recorder turned on, or
+    off, as it starts."""
+    from repro.core import trace as tracer
+
+    def call(*a, **k):
+        (tracer.enable if on else tracer.disable)()
+        return drive(*a, **k)
+    return call
 
 
 def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
@@ -39,7 +50,6 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     through the traffic; returns its result line's numbers with the
     recorder's beside them."""
     import jax
-    from repro.core import trace as tracer
     got = {}
     own_drive, own_load = serve.drive, devtrace.load
     own_prof = jax.profiler.start_trace, jax.profiler.stop_trace
@@ -54,36 +64,27 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
                 prof.append((op, t, time.perf_counter() - t))
         return call
 
-    def drive(cluster, arrivals, t0, stop, rec, base=0.0, on_step=None):
-        if recorder:
-            tracer.enable()
-        try:
-            tracks = own_drive(cluster, arrivals, t0, stop, rec, base, on_step)
-        finally:
-            tracer.disable()
-        got.update(tracks=tracks, rec=rec, close=stop, records=tracer.drain())
-        return tracks
-
-    serve.drive, devtrace.load = drive, spans.load
+    if recorder != trace:           # run.measure records in traced runs
+        serve.drive = _recorder(recorder, own_drive)
+    devtrace.load = spans.load
     jax.profiler.start_trace = timed("start", own_prof[0])
     jax.profiler.stop_trace = timed("stop", own_prof[1])
     try:
-        line = run.measure(cell, seed, seconds, trace, devices)
+        line = run.measure(cell, seed, seconds, trace, devices,
+                           on_run=lambda r: got.update(run=r))
     finally:
         serve.drive, devtrace.load = own_drive, own_load
         jax.profiler.start_trace, jax.profiler.stop_trace = own_prof
-    close = got["close"]
-    open_ = close - seconds
-    recs, rec = got["records"], got["rec"]
+    ran = got["run"]
+    open_, close, recs = ran.open, ran.close, ran.spans
     a, b = open_ * 1e9, close * 1e9
-    view = types.SimpleNamespace(rec=rec, open=open_, close=close)
     firsts = {tr.arrival.idx: (tr.due, tr.first)
-              for tr in stats.in_window(got["tracks"], open_, close)
+              for tr in stats.in_window(ran.tracks, open_, close)
               if tr.first is not None and tr.first < close}
     out = {"workload": cell.name, "seed": seed, "trace": int(trace),
            "recorder": int(recorder), "correct": line["correct"],
            "metrics": {k: m["value"] for k, m in line["metrics"].items()},
-           "harness": {k: spec.metric_reader(k).read(view)
+           "harness": {k: spec.metric_reader(k).read(ran)
                        for k in ("prefill_ms", "decode_step_ms")},
            "program": {k: f(recs, a, b) for k, f in spans.READERS.items()},
            "ttft_parts_ms": spans.ttft_parts(recs, firsts),
