@@ -21,11 +21,16 @@ import jax.numpy as jnp
 import numpy as np
 
 
-@functools.partial(jax.jit, static_argnames=("num_experts",))
-def accumulate_stats(expert_ids: jax.Array, num_experts: int
-                     ) -> Tuple[jax.Array, jax.Array]:
+@functools.partial(jax.jit, static_argnames=("num_experts", "held_only"))
+def accumulate_stats(expert_ids: jax.Array, num_experts: int,
+                     held_only: bool = False) -> Tuple[jax.Array, jax.Array]:
     """expert_ids: (L, B, S, K) int32 logical expert ids.
-    Returns (A (L, E) int32 counts, W (E, E) int32 inter-layer pair counts)."""
+    Returns (A (L, E) int32 counts, W (E, E) int32 inter-layer pair counts).
+    ``held_only``: ids >= E (experts held elsewhere) count nowhere."""
+    if held_only:
+        ids = jnp.minimum(expert_ids, num_experts)     # E: the one "elsewhere"
+        a, w = accumulate_stats(ids, num_experts + 1)
+        return a[:, :num_experts], w[:num_experts, :num_experts]
     l, b, s, k = expert_ids.shape
     flat = expert_ids.reshape(l, b * s, k)
     a = jax.vmap(lambda ids: jnp.zeros((num_experts,), jnp.int32)
@@ -42,9 +47,13 @@ class AffinityTracker:
     """Host-side accumulator with exponential decay (recent traffic dominates,
     matching the paper's 'recent activation statistics' in Alg. 3)."""
 
-    def __init__(self, num_layers: int, num_experts: int, decay: float = 1.0):
+    def __init__(self, num_layers: int, num_experts: int, decay: float = 1.0,
+                 held_only: bool = False):
+        """``held_only``: the ids fed may name experts >= num_experts, held
+        elsewhere, which are not counted."""
         self.num_layers = num_layers
         self.num_experts = num_experts
+        self.held_only = held_only
         self.decay = decay
         self.A = np.zeros((num_layers, num_experts), np.float64)
         self.W = np.zeros((num_experts, num_experts), np.float64)
@@ -52,7 +61,7 @@ class AffinityTracker:
 
     def update(self, expert_ids) -> None:
         ids = jnp.asarray(expert_ids)
-        a, w = accumulate_stats(ids, self.num_experts)
+        a, w = accumulate_stats(ids, self.num_experts, self.held_only)
         if self.decay < 1.0:
             self.A *= self.decay
             self.W *= self.decay

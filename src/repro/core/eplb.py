@@ -81,7 +81,11 @@ class ExpertRebalancer:
         assert (e + redundancy) % num_devices == 0, \
             f"device count {num_devices} must divide E+R={e + redundancy}"
         n_moe = sum(model_cfg.layer_is_moe(i) for i in range(model_cfg.num_layers))
-        self.tracker = AffinityTracker(max(n_moe, 1), e, decay=stats_decay)
+        # a model holding a share of the router's experts observes, places
+        # and relocates only the held ones (the ids of the others are fed
+        # too, and not counted)
+        self.tracker = AffinityTracker(max(n_moe, 1), e, decay=stats_decay,
+                                       held_only=model_cfg.holds_share)
         # initial layout: the unreplicated static placement even when R > 0 —
         # physical backends start with exactly E weight rows, and replicas
         # only materialize when the first rebalance targets the observed hot

@@ -28,9 +28,16 @@ The spans and where they open:
                               ``SchedulerCore.submit`` or a victim's
                               re-queue to its admission (``wait_open`` /
                               ``wait_close``); not annotated
-  ``backend.prefill``         ``JaxBackend.start``; ``plen``, ``bucket``
+  ``backend.prefill``         ``JaxBackend.start``; ``plen``, ``bucket``;
+                              ``held_rows`` (the ``moe.held_rows``
+                              counter) where the model holds a share of
+                              its routed experts: the prompt's routed
+                              (token, expert) pairs on held experts
   ``backend.decode``          ``JaxBackend.decode``; ``rows``,
-                              ``max_slots``
+                              ``max_slots``; ``held_rows`` as above (of
+                              the active rows); ``latent_tokens`` on
+                              paged MLA latents: the resident tokens the
+                              step's attention read, new ones included
   ``backend.decode.wait``     the host's read of the decoded tokens
   ``backend.relocate``        ``JaxBackend.apply_placement``
   ``expert.observe``          ``ExpertRebalancer.observe``

@@ -17,6 +17,12 @@ decode attention is KV-bandwidth-bound).
 
 ``flash_decode`` (a contiguous (B, S, Hkv, D) cache) is the same kernel over
 that cache cut into private pages with an identity block table.
+
+``flash_decode_latent`` is the MLA twin over latent pages (L, P, BS, R) and
+(L, P, BS, Dr) of every layer: with the queries absorbed into latent space
+the attention is an MQA, so grid (B, NB) reads one page per step for all
+heads, with the same scalar-prefetched tables, masked-page skip and f32
+online softmax.
 """
 from __future__ import annotations
 
@@ -167,3 +173,113 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, lengths: jax.Array,
     tables = jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
     return flash_decode_paged(q, pages(k), pages(v), tables, lengths,
                               softcap=softcap, interpret=interpret)
+
+
+# =============================================================================
+# Latent pages (MLA, weight-absorbed)
+# =============================================================================
+
+def _latent_kernel(layer_ref, bt_ref, len_ref, ql_ref, qr_ref, c_ref, r_ref,
+                   o_ref, m_ref, l_ref, acc_ref, *, block_s: int,
+                   scale: float):
+    bi = pl.program_id(0)
+    si = pl.program_id(1)
+    n_s = pl.num_programs(1)
+
+    @pl.when(si == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    length = len_ref[bi]
+
+    @pl.when(si * block_s < length)                  # masked pages: skipped
+    def _update():
+        ql = ql_ref[0].astype(jnp.float32)           # (H, R)
+        qr = qr_ref[0].astype(jnp.float32)           # (H, Dr)
+        c = c_ref[0, 0].astype(jnp.float32)          # (BS, R)
+        kr = r_ref[0, 0].astype(jnp.float32)         # (BS, Dr)
+        dims = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(ql, c, dims, preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr, kr, dims,
+                                   preferred_element_type=jnp.float32))
+        s = s * scale                                # (H, BS)
+        jpos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(jpos < length, s, NEG_INF)
+
+        m_prev = m_ref[...]                          # (H, 1)
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p, c, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(si == n_s - 1)
+    def _finish():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
+                    ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def flash_decode_latent(q_lat: jax.Array, q_rope: jax.Array,
+                        ckv_pages: jax.Array, krope_pages: jax.Array,
+                        layer: jax.Array, block_tables: jax.Array,
+                        lengths: jax.Array, *, scale: float,
+                        interpret: bool = False) -> jax.Array:
+    """Flash decode over paged MLA latents (weight-absorbed MQA).
+
+    q_lat: (B, H, R) queries already in latent space (q_nope W_UK);
+    q_rope: (B, H, Dr); ckv_pages: (L, P, BS, R) and krope_pages: (L, P,
+    BS, Dr), the latent page pools of every layer, read in place at
+    ``layer`` (int32 scalar); block_tables: (B, NB); lengths: (B,) valid
+    tokens per row; ``scale``: the softmax scale.  Returns o_lat (B, H, R),
+    softmax(q_lat ckv^T + q_rope krope^T) ckv.
+
+    Grid (B, NB): each step reads one latent page (BS, R) + (BS, Dr) and
+    every head attends it, with the (m, l, acc) online-softmax state of
+    flash_decode_paged carried across pages in VMEM.  The layer index, the
+    block table and the lengths ride in as scalar prefetch; a masked page
+    maps to the row's last valid page, so the pipeline fetches nothing new
+    for it, and its compute is skipped."""
+    b, h, r = q_lat.shape
+    dr = q_rope.shape[-1]
+    _, _, bs, _ = ckv_pages.shape
+    nb = block_tables.shape[1]
+
+    def q_map(bi, si, ly, bt, ln):
+        return (bi, 0, 0)
+
+    def page_map(bi, si, ly, bt, ln):
+        last = jnp.maximum((ln[bi] + bs - 1) // bs - 1, 0)
+        return (ly[0], bt[bi, jnp.minimum(si, last)], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, nb),
+        in_specs=[
+            pl.BlockSpec((1, h, r), q_map),
+            pl.BlockSpec((1, h, dr), q_map),
+            pl.BlockSpec((1, 1, bs, r), page_map),
+            pl.BlockSpec((1, 1, bs, dr), page_map),
+        ],
+        out_specs=pl.BlockSpec((1, h, r), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),         # m
+            pltpu.VMEM((h, 1), jnp.float32),         # l
+            pltpu.VMEM((h, r), jnp.float32),         # acc
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, block_s=bs, scale=scale),
+        grid_spec=grid_spec,
+        name="flash_decode_latent",
+        out_shape=jax.ShapeDtypeStruct((b, h, r), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      q_lat, q_rope, ckv_pages, krope_pages)

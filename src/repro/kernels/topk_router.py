@@ -39,8 +39,40 @@ import jax.experimental.pallas.tpu as pltpu
 NEG_INF = -2.0 ** 30
 
 
+def _group_limit(probs, lane_e, n_group: int, topk_group: int):
+    """DeepSeek-V2's group_limited_greedy mask: the experts split into
+    ``n_group`` equal lane groups, each scored by its best prob; the probs
+    outside the ``topk_group`` best groups become 0 (lowest group first
+    among ties)."""
+    bt, e = probs.shape
+    gs = e // n_group
+    lane_g = jax.lax.broadcasted_iota(jnp.int32, (bt, n_group), 1
+                                      ).astype(jnp.float32)
+    in_g = [(lane_e >= g * gs) & (lane_e < (g + 1) * gs)
+            for g in range(n_group)]
+    gscore = jnp.zeros((bt, n_group), jnp.float32)
+    for g in range(n_group):
+        best = jnp.max(jnp.where(in_g[g], probs, NEG_INF), -1, keepdims=True)
+        gscore = jnp.where(lane_g == g, best, gscore)
+    chosen = jnp.zeros((bt, n_group), jnp.float32)
+    for _ in range(topk_group):
+        val = gscore.max(-1, keepdims=True)
+        gi = jnp.min(jnp.where(gscore == val, lane_g, float(n_group)), -1,
+                     keepdims=True)
+        hit = lane_g == gi
+        chosen = jnp.where(hit, 1.0, chosen)
+        gscore = jnp.where(hit, NEG_INF, gscore)
+    keep = jnp.zeros_like(probs)
+    for g in range(n_group):
+        cg = jnp.sum(jnp.where(lane_g == g, chosen, 0.0), -1, keepdims=True)
+        keep = jnp.where(in_g[g], cg, keep)
+    return jnp.where(keep > 0, probs, 0.0)
+
+
 def _kernel(x_ref, rs_ref, rc_ref, gates_ref, ids_ref, slots_ref, pos_ref,
-            count_ref, *, k: int, num_slots: int, replicated: bool):
+            count_ref, *, k: int, num_slots: int, replicated: bool,
+            n_group: int = 1, topk_group: int = 1, scale: float = 1.0,
+            renorm: bool = True):
     ti = pl.program_id(0)
 
     @pl.when(ti == 0)
@@ -59,6 +91,8 @@ def _kernel(x_ref, rs_ref, rc_ref, gates_ref, ids_ref, slots_ref, pos_ref,
     lane_s = iota(jnp.int32, (bt, num_slots), 1)
 
     work = probs
+    if n_group > 1:
+        work = _group_limit(probs, lane_e, n_group, topk_group)
     gates = jnp.zeros((bt, k), jnp.float32)
     ids = jnp.zeros((bt, k), jnp.int32)
     slots = jnp.zeros((bt, k), jnp.int32)
@@ -91,7 +125,10 @@ def _kernel(x_ref, rs_ref, rc_ref, gates_ref, ids_ref, slots_ref, pos_ref,
         slots = jnp.where(lane_k == j, slot, slots)
         occ = occ + (lane_s == slot).astype(jnp.float32)
         sel_slots.append(slot)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if renorm:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        gates = gates * scale
 
     # capacity positions: token-major then selection order (GShard rule),
     # counted per PHYSICAL slot and carried across blocks in count_ref
@@ -112,7 +149,8 @@ def _kernel(x_ref, rs_ref, rc_ref, gates_ref, ids_ref, slots_ref, pos_ref,
 
 
 def _call(logits: jax.Array, k: int, replica_slots, replica_count,
-          num_slots: int, block_t: int, interpret: bool):
+          num_slots: int, block_t: int, interpret: bool, n_group: int = 1,
+          topk_group: int = 1, scale: float = 1.0, renorm: bool = True):
     t, e = logits.shape
     bt = min(block_t, t)
     tp = -(-t // bt) * bt
@@ -131,7 +169,8 @@ def _call(logits: jax.Array, k: int, replica_slots, replica_count,
     rc = jnp.asarray(replica_count, jnp.float32).reshape(1, e)
     gates, ids, slots, pos = pl.pallas_call(
         functools.partial(_kernel, k=k, num_slots=num_slots,
-                          replicated=replicated),
+                          replicated=replicated, n_group=n_group,
+                          topk_group=topk_group, scale=scale, renorm=renorm),
         grid=(tp // bt,),
         in_specs=[
             pl.BlockSpec((bt, e), lambda ti: (ti, 0)),
@@ -168,14 +207,24 @@ def topk_router(logits: jax.Array, k: int, *, block_t: int = 256,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("k", "num_slots", "block_t", "interpret"))
+                   static_argnames=("k", "num_slots", "block_t", "interpret",
+                                    "n_group", "topk_group", "scale",
+                                    "renorm"))
 def topk_router_replicated(logits: jax.Array, k: int,
                            replica_slots: jax.Array, replica_count: jax.Array,
                            num_slots: int, *, block_t: int = 256,
-                           interpret: bool = False):
+                           interpret: bool = False, n_group: int = 1,
+                           topk_group: int = 1, scale: float = 1.0,
+                           renorm: bool = True):
     """Replica-aware router.  replica_slots: (E, max_rep) physical slots per
     logical expert (padded with the primary); replica_count: (E,);
     num_slots: S = E + R.  Returns (gates (T,k) f32, ids (T,k) i32 logical,
-    slots (T,k) i32 physical, pos (T,k) i32 position-within-slot)."""
+    slots (T,k) i32 physical, pos (T,k) i32 position-within-slot).
+
+    A slot of ``num_slots`` in the table marks an expert held elsewhere: its
+    selections are returned with that slot and take no capacity position.
+    ``n_group`` > 1 limits the top-k to the ``topk_group`` best expert
+    groups (group_limited_greedy); the gates are renormalised to sum 1 when
+    ``renorm``, then multiplied by ``scale``."""
     return _call(logits, k, replica_slots, replica_count, num_slots,
-                 block_t, interpret)
+                 block_t, interpret, n_group, topk_group, scale, renorm)

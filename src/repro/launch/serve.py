@@ -99,7 +99,9 @@ def build_cluster(cfg: ModelConfig, variant: str, n_engines: int,
                   devices: Optional[Sequence] = None) -> Cluster:
     """``n_engines`` replicas of one model behind the router.  Engine *i*
     serves from ``devices[i % len(devices)]`` (default ``jax.devices()``)
-    with the same weights (one key)."""
+    with the same weights (one key).  The expert level places the routed
+    experts the model holds (``cfg.num_experts``: a share of the router's
+    ``router_width`` where the chip holds one) over up to 4 devices."""
     devices = list(devices) if devices is not None else jax.devices()
     params = init_params(cfg, device=devices[0])
     engines = []

@@ -100,15 +100,17 @@ def _expand_kv(k: jax.Array, hq: int) -> jax.Array:
     return _head_constraint(k)
 
 
-def _sdpa(cfg: ModelConfig, q, k, v, mask) -> jax.Array:
-    """q: (B,Sq,Hq,D)  k,v: (B,Skv,Hkv,D)  mask: broadcastable to (B,Sq,Skv)."""
+def _sdpa(cfg: ModelConfig, q, k, v, mask, scale=None) -> jax.Array:
+    """q: (B,Sq,Hq,D)  k,v: (B,Skv,Hkv,D)  mask: broadcastable to (B,Sq,Skv).
+    ``scale``: the softmax scale (default D**-0.5)."""
     b, sq, hq, d = q.shape
     k = _expand_kv(k, hq)
     v = _expand_kv(v, hq)
     # scores leave the MXU in f32 (no bf16 rounding before the softmax), as
     # kernels/flash_decode.py computes them
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) * (d ** -0.5)
+                        preferred_element_type=jnp.float32) * (
+                            d ** -0.5 if scale is None else scale)
     if cfg.attn_logit_softcap > 0:
         scores = softcap(scores, cfg.attn_logit_softcap)
     scores = jnp.where(mask[:, None, :, :], scores, NEG_INF)
@@ -127,7 +129,7 @@ def _causal_mask(sq: int, skv: int, window: int) -> jax.Array:
 
 
 def _sdpa_chunked(cfg: ModelConfig, q, k, v, window: int, causal: bool = True,
-                  q_chunk: int = Q_CHUNK) -> jax.Array:
+                  q_chunk: int = Q_CHUNK, scale=None) -> jax.Array:
     """Memory-bounded full-sequence attention: scan over query chunks so only
     a (q_chunk, Skv) score block is live at a time (flash-attention-lite in
     pure XLA; kernels/flash_decode.py shows the full-Pallas treatment).
@@ -140,7 +142,7 @@ def _sdpa_chunked(cfg: ModelConfig, q, k, v, window: int, causal: bool = True,
     n_chunks = sq // qc
     k = _expand_kv(k, hq)
     v = _expand_kv(v, hq)
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     j = jnp.arange(skv)[None, :]
 
     def one(ci):
@@ -167,7 +169,8 @@ def _sdpa_chunked(cfg: ModelConfig, q, k, v, window: int, causal: bool = True,
     return out.transpose(1, 0, 2, 3, 4).reshape(b, sq, hq, v.shape[-1])
 
 
-def _sdpa_auto(cfg: ModelConfig, q, k, v, window: int, causal: bool = True):
+def _sdpa_auto(cfg: ModelConfig, q, k, v, window: int, causal: bool = True,
+               scale=None, q_chunk: int = Q_CHUNK):
     """Pick chunked vs. materialized scores by footprint.
 
     When the head count doesn't divide the TP degree (gemma2 8H / llama4 40H
@@ -182,12 +185,12 @@ def _sdpa_auto(cfg: ModelConfig, q, k, v, window: int, causal: bool = True):
             and divides(q.shape[1], ctx.tp)):
         mask = _causal_mask(q.shape[1], k.shape[1], window) if causal else \
             jnp.ones((1, q.shape[1], k.shape[1]), bool)
-        return _sdpa(cfg, q, k, v, mask)
+        return _sdpa(cfg, q, k, v, mask, scale)
     if q.shape[1] * k.shape[1] > CHUNK_THRESHOLD and q.shape[1] > 1:
-        return _sdpa_chunked(cfg, q, k, v, window, causal)
+        return _sdpa_chunked(cfg, q, k, v, window, causal, q_chunk, scale)
     mask = _causal_mask(q.shape[1], k.shape[1], window) if causal else \
         jnp.ones((1, q.shape[1], k.shape[1]), bool)
-    return _sdpa(cfg, q, k, v, mask)
+    return _sdpa(cfg, q, k, v, mask, scale)
 
 
 def gqa_full(params: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
@@ -449,7 +452,8 @@ def _mla_q(params: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array):
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling,
+                        cfg.rope_interleave)
     return q_nope, q_rope
 
 
@@ -457,8 +461,20 @@ def _mla_ckv(params: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array)
     r_kv, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     kv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])
     ckv = rms_norm(kv[..., :r_kv], params["kv_norm"], cfg.norm_eps)
-    krope = apply_rope(kv[..., None, r_kv:], positions, cfg.rope_theta)[..., 0, :]
+    krope = apply_rope(kv[..., None, r_kv:], positions, cfg.rope_theta,
+                       cfg.rope_scaling, cfg.rope_interleave)[..., 0, :]
     return ckv, krope
+
+
+def _score_chunk(heads: int, skv: int, budget: int = 1 << 26) -> int:
+    """Query rows per chunk of the chunked prefill attention: Q_CHUNK, halved
+    until one chunk's (heads, rows, skv) f32 scores hold at most ``budget``
+    elements (256 MB): deepseek-v2's 128 heads over an 8192-token prompt
+    take 64 rows, where 512 would make 2 GB of scores."""
+    qc = Q_CHUNK
+    while qc > 8 and heads * qc * skv > budget:
+        qc //= 2
+    return qc
 
 
 def mla_full(params: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
@@ -478,7 +494,9 @@ def mla_full(params: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(krope[:, :, None, :],
                                                   (*krope.shape[:2], cfg.num_heads, krope.shape[-1]))], axis=-1)
-    out = _sdpa_auto(cfg, q, k, v, 0, causal=True)
+    out = _sdpa_auto(cfg, q, k, v, 0, causal=True,
+                     scale=cfg.attn_softmax_scale,
+                     q_chunk=_score_chunk(cfg.num_heads, k.shape[1]))
     out = jnp.einsum("bshk,hkd->bsd", out[..., :dv], params["wo"])
     return out, new_cache
 
@@ -512,7 +530,7 @@ def mla_decode(params: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
 
     s_max = ckv.shape[1]
     mask = jnp.arange(s_max)[None, :] <= cache_pos[:, None]      # (B, Skv)
-    scale = (dn + cfg.qk_rope_head_dim) ** -0.5
+    scale = cfg.attn_softmax_scale
     ckv_c = ckv.astype(x.dtype)
     krope_c = krope.astype(x.dtype)
 
@@ -539,6 +557,64 @@ def mla_decode(params: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
     return out, new_cache
 
 
+def mla_decode_paged(params: dict, cfg: ModelConfig, x: jax.Array, pages: dict,
+                     layer: jax.Array, block_tables: jax.Array,
+                     lengths: jax.Array, use_kernel: bool = False,
+                     interpret: bool = False):
+    """One-token MLA decode against the paged latent pool of EVERY layer.
+
+    x: (B,1,d); pages: {"ckv": (L,P,BS,R), "krope": (L,P,BS,Dr)}, the whole
+    stack's pages (serving/kvcache.PagedKVCache), written and read in place
+    at ``layer`` (an int32 scalar); block_tables (B,NB) and lengths (B,) as
+    gqa_decode_paged.  Returns (out (B,1,d), new pages).
+
+    Weight-absorbed: the queries move into latent space (q_nope W_UK) before
+    the attention, so every head attends the one shared latent, an MQA of
+    R + Dr for scores and R for values; o_lat W_UV and wo follow it.
+    ``use_kernel`` runs that attention through the Pallas
+    flash_decode_latent kernel (interpreted under ``interpret``), else
+    through a gather of the row's pages in XLA."""
+    dn = cfg.qk_nope_head_dim
+    q_nope, q_rope = _mla_q(params, cfg, x, lengths[:, None])
+    ckv_new, krope_new = _mla_ckv(params, cfg, x, lengths[:, None])
+    b = x.shape[0]
+    bs = pages["ckv"].shape[2]
+    phys = block_tables[jnp.arange(b), lengths // bs]          # (B,)
+    off = lengths % bs
+    new_pages = {
+        "ckv": pages["ckv"].at[layer, phys, off].set(
+            ckv_new[:, 0].astype(pages["ckv"].dtype)),
+        "krope": pages["krope"].at[layer, phys, off].set(
+            krope_new[:, 0].astype(pages["krope"].dtype)),
+    }
+    wkb_k = params["wkv_b"][..., :dn]                          # (R, H, dn)
+    wkb_v = params["wkv_b"][..., dn:]                          # (R, H, dv)
+    q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, wkb_k)[:, 0]  # (B, H, R)
+    q_pe = q_rope[:, 0]                                        # (B, H, Dr)
+    scale = cfg.attn_softmax_scale
+    if use_kernel:
+        from repro.kernels.flash_decode import flash_decode_latent
+        o_lat = flash_decode_latent(
+            q_lat, q_pe, new_pages["ckv"], new_pages["krope"], layer,
+            block_tables, lengths + 1, scale=scale, interpret=interpret)
+    else:
+        nb = block_tables.shape[1]
+        c = new_pages["ckv"][layer][block_tables].reshape(b, nb * bs, -1)
+        kr = new_pages["krope"][layer][block_tables].reshape(b, nb * bs, -1)
+        c, kr = c.astype(x.dtype), kr.astype(x.dtype)
+        scores = (jnp.einsum("bhr,btr->bht", q_lat, c,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bhk,btk->bht", q_pe, kr,
+                               preferred_element_type=jnp.float32)) * scale
+        valid = jnp.arange(nb * bs)[None, :] <= lengths[:, None]
+        scores = jnp.where(valid[:, None, :], scores, NEG_INF)
+        w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        o_lat = jnp.einsum("bht,btr->bhr", w, c)
+    out = jnp.einsum("bhr,rhk->bhk", o_lat.astype(x.dtype), wkb_v)
+    out = jnp.einsum("bhk,hkd->bd", out, params["wo"])[:, None]
+    return out, new_pages
+
+
 def _mla_decode_seqsharded(cfg: ModelConfig, params, q_nope, q_rope, ckv_new,
                            krope_new, cache, cache_pos, ctx, absorb: bool):
     """Seq-sharded MLA decode against the compressed cache (flash-decode
@@ -552,7 +628,7 @@ def _mla_decode_seqsharded(cfg: ModelConfig, params, q_nope, q_rope, ckv_new,
     for a in ctx.batch_axes:
         bdim *= int(ctx.mesh.shape[a])
     b_ax = ctx.batch_axes if divides(b, bdim) else None
-    scale = (dn + cfg.qk_rope_head_dim) ** -0.5
+    scale = cfg.attn_softmax_scale
     wkb = params["wkv_b"]                       # (r, H, dn+dv) replicated inside
 
     if absorb:

@@ -19,8 +19,12 @@ from repro.models.layers import ffn_apply, init_ffn, init_rms_norm, rms_norm
 
 
 def _moe_sharded(cfg: ModelConfig) -> bool:
+    """Whether the expert-parallel shard_map path applies: under a shard
+    context, with every routed expert held (a held share routes on one
+    device)."""
     ctx = current_ctx()
-    return ctx is not None and cfg.num_experts % ctx.tp == 0
+    return (ctx is not None and cfg.num_experts % ctx.tp == 0
+            and not cfg.holds_share)
 
 
 def moe_reads_stacked(cfg: ModelConfig, dispatch_mode: str) -> bool:
@@ -140,8 +144,8 @@ def attn_block_decode_paged(p: dict, cfg: ModelConfig, x, cache, block_tables,
                             lengths, is_local, is_moe_layer: bool, placement,
                             dispatch_mode: str, stats: bool,
                             use_kernel: bool = False, interpret: bool = False):
-    """attn_block_decode against one layer's paged KV pool (GQA only;
-    PagedKVCache rejects other families up front)."""
+    """attn_block_decode against one layer's paged KV pool (GQA; MLA takes
+    attn_block_decode_latent)."""
     h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps)
     if (cfg.sliding_window > 0 and cfg.local_global_period > 0
             and not isinstance(is_local, bool)):
@@ -169,6 +173,29 @@ def attn_block_decode_paged(p: dict, cfg: ModelConfig, x, cache, block_tables,
         y = ffn_apply(p["ffn"], h)
     x = x + y
     return x, new_cache, aux
+
+
+def attn_block_decode_latent(p: dict, cfg: ModelConfig, x, pages, layer,
+                             block_tables, lengths, is_moe_layer: bool,
+                             placement, dispatch_mode: str, stats: bool,
+                             use_kernel: bool = False,
+                             interpret: bool = False):
+    """attn_block_decode for MLA against the paged latent pool of every
+    layer (attention.mla_decode_paged), written and read at ``layer``.
+    Returns (x, new pages, aux)."""
+    h = rms_norm(x, p["attn_norm"]["scale"], cfg.norm_eps)
+    a, pages = attn.mla_decode_paged(p["attn"], cfg, h, pages, layer,
+                                     block_tables, lengths, use_kernel,
+                                     interpret)
+    x = x + a
+    h = rms_norm(x, p["ffn_norm"]["scale"], cfg.norm_eps)
+    aux = {}
+    if is_moe_layer:
+        y, aux = _moe(p["moe"], cfg, h, placement, dispatch_mode, stats,
+                      interpret)
+    else:
+        y = ffn_apply(p["ffn"], h)
+    return x + y, pages, aux
 
 
 # --- apply: mamba block --------------------------------------------------------------

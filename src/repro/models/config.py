@@ -8,9 +8,31 @@ functions safely.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+import math
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling as DeepSeek-V2 publishes it (``rope_scaling`` with
+    ``type: yarn``): frequencies interpolated by ``factor`` past the
+    correction range, extrapolated below it, and the softmax scale of the
+    attention multiplied by ``mscale(factor, mscale_all_dim)**2``."""
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,6 +51,8 @@ class ModelConfig:
     vocab_size: int = 1000
     norm_eps: float = 1e-6
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnScaling] = None   # None: plain rope
+    rope_interleave: bool = False      # rotate (x[2i], x[2i+1]) pairs (deepseek-v2)
     tie_embeddings: bool = False
 
     # --- attention variants ---------------------------------------------------
@@ -47,9 +71,16 @@ class ModelConfig:
     v_head_dim: int = 0
 
     # --- MoE -------------------------------------------------------------------
-    num_experts: int = 0
+    num_experts: int = 0               # routed experts this model holds (their weights)
+    n_routed_experts: int = 0          # the router's width; 0 -> num_experts.  A
+                                       # larger router holds a share: experts
+                                       # 0..num_experts-1 (the rest are elsewhere)
     num_shared_experts: int = 0
     moe_top_k: int = 0
+    n_group: int = 1                   # group-limited routing: experts in n_group
+    topk_group: int = 1                # groups, top-k only inside the best topk_group
+    routed_scaling_factor: float = 1.0  # gates x this (deepseek-v2: 16)
+    norm_topk_prob: bool = True        # renormalise the top-k gates to sum 1
     moe_d_ff: int = 0                  # per-expert FFN width
     first_k_dense: int = 0             # leading layers that use a dense FFN instead
     moe_every: int = 1                 # layer i is MoE iff i >= first_k_dense and i % moe_every == 0
@@ -101,6 +132,27 @@ class ModelConfig:
     @property
     def is_hybrid(self) -> bool:
         return self.ssm_state > 0 and self.shared_attn_every > 0
+
+    @property
+    def router_width(self) -> int:
+        """Routed experts the router scores (the published count)."""
+        return self.n_routed_experts or self.num_experts
+
+    @property
+    def holds_share(self) -> bool:
+        """Whether the model holds only a share of its routed experts."""
+        return self.is_moe and self.router_width > self.num_experts
+
+    @property
+    def attn_softmax_scale(self) -> float:
+        """The attention's softmax scale: q_head_dim**-0.5, times
+        mscale(factor, mscale_all_dim)**2 under YaRN (deepseek-v2)."""
+        scale = self.q_head_dim ** -0.5
+        ys = self.rope_scaling
+        if ys is not None and ys.mscale_all_dim:
+            m = yarn_mscale(ys.factor, ys.mscale_all_dim)
+            scale = scale * m * m
+        return scale
 
     @property
     def ssm_d_inner(self) -> int:

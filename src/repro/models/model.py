@@ -365,10 +365,12 @@ def forward(params, cfg: ModelConfig, tokens: Optional[jax.Array] = None, *,
             cache=None, cache_pos=None, decode: bool = False,
             vision_embeds=None, frames=None,
             placements=None, dispatch_mode: str = "dense", stats: bool = False,
-            mla_absorb: bool = False, interpret: bool = False):
+            mla_absorb: bool = False, interpret: bool = False, head_at=None):
     """One entry point for train-forward (cache=None), prefill (cache given,
     full seq) and decode (decode=True, one token).  ``interpret`` runs the
     Pallas kernels of dispatch_mode="fused" in the interpreter (CPU).
+    ``head_at`` (an int32 scalar) computes the logits of that position
+    alone: (B, 1, V), not (B, S, V).
 
     Returns (logits, new_cache, aux).  logits: (B, S, V) fp32.
     """
@@ -431,6 +433,8 @@ def forward(params, cfg: ModelConfig, tokens: Optional[jax.Array] = None, *,
                 new_cache["prologue"] = pro_caches
 
     # ---- head ---------------------------------------------------------------------
+    if head_at is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, head_at, 1, axis=1)
     x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     unemb = params["embed"] if cfg.tie_embeddings else params["embed"]
     w = unemb["embedding"] if cfg.tie_embeddings else unemb["unembedding"]
@@ -554,11 +558,17 @@ def decode_step_paged(params, cfg: ModelConfig, token, pages, block_tables,
     token: (B, 1) int32; pages: per-layer page pytree with leading L
     ({"k": (L,P,Hkv,BS,D), "v": ..., optional "k_scale"/"v_scale": (L,P)});
     block_tables: (B, NB) int32; lengths: (B,) tokens resident per row.
-    Homogeneous GQA stacks only (no prologue / hybrid / MLA — PagedKVCache
-    enforces this at construction).  ``use_kernel`` takes attention through
-    flash_decode_paged; ``interpret`` runs every Pallas kernel of the step in
-    the interpreter (CPU) instead of compiling it.  Returns (logits (B,V),
-    new_pages, aux)."""
+    A homogeneous GQA stack, or an MLA stack with a dense prologue
+    (``{"ckv": (L,P,BS,R), "krope": (L,P,BS,Dr)}`` latent pages of every
+    layer, _decode_step_paged_latent); PagedKVCache refuses the other
+    families.  ``use_kernel`` takes attention through flash_decode_paged
+    (flash_decode_latent for MLA); ``interpret`` runs every Pallas kernel of
+    the step in the interpreter (CPU) instead of compiling it.  Returns
+    (logits (B,V), new_pages, aux)."""
+    if cfg.attention_type == "mla":
+        return _decode_step_paged_latent(
+            params, cfg, token, pages, block_tables, lengths, placements,
+            dispatch_mode, stats, use_kernel, interpret)
     x = embed_apply(params["embed"], token)
     flags = local_flags(cfg)
     is_moe = cfg.is_moe
@@ -585,3 +595,44 @@ def decode_step_paged(params, cfg: ModelConfig, token, pages, block_tables,
     w = unemb["embedding"] if cfg.tie_embeddings else unemb["unembedding"]
     logits = unembed_apply({"unembedding": w}, x, cfg.final_logit_softcap)
     return logits[:, -1], new_pages, aux
+
+
+def _decode_step_paged_latent(params, cfg: ModelConfig, token, pages,
+                              block_tables, lengths, placements,
+                              dispatch_mode: str, stats: bool,
+                              use_kernel: bool, interpret: bool):
+    """decode_step_paged of an MLA stack: the dense prologue layers, then a
+    scan over the rest (MoE), every layer writing and reading the latent
+    pool of all layers in place at its own index (the pool rides in the
+    scan's carry, so no layer's pages are sliced out or stacked anew).
+    Expert weights are hoisted for the scanned MoE stack only."""
+    x = embed_apply(params["embed"], token)
+    n_pro = cfg.first_k_dense if cfg.is_moe else 0
+    for i in range(n_pro):
+        x, pages, _ = B.attn_block_decode_latent(
+            params["prologue"][i], cfg, x, pages, jnp.int32(i), block_tables,
+            lengths, False, None, dispatch_mode, False, use_kernel, interpret)
+    pstack = _placement_stack(cfg, placements)
+    blocks, stacked, layers = _hoist_expert_weights(cfg, params["blocks"],
+                                                    dispatch_mode)
+    depth = n_pro + jnp.arange(cfg.num_layers - n_pro, dtype=jnp.int32)
+
+    def body(carry, xs):
+        x, pages = carry
+        p, inv, layer, at = xs
+        p = _with_layer(p, stacked, layer)
+        plc = (ExpertPlacement.from_slot_map(inv, cfg.num_experts)
+               if inv is not None else None)
+        x, pages, aux = B.attn_block_decode_latent(
+            p, cfg, x, pages, at, block_tables, lengths, cfg.is_moe, plc,
+            dispatch_mode, stats, use_kernel, interpret)
+        return (x, pages), aux
+
+    (x, pages), auxs = jax.lax.scan(body, (x, pages),
+                                    (blocks, pstack, layers, depth),
+                                    unroll=_unroll())
+    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
+    w = (params["embed"]["embedding"] if cfg.tie_embeddings
+         else params["embed"]["unembedding"])
+    logits = unembed_apply({"unembedding": w}, x, cfg.final_logit_softcap)
+    return logits[:, -1], pages, _agg_aux(auxs)

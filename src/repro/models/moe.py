@@ -114,7 +114,8 @@ def init_moe(key, cfg: ModelConfig) -> dict:
     ks = jax.random.split(key, 5)
     s_in, s_out = d ** -0.5, f ** -0.5
     p = {
-        "w_router": (jax.random.normal(ks[0], (d, e)) * s_in).astype(jnp.float32),
+        "w_router": (jax.random.normal(ks[0], (d, cfg.router_width))
+                     * s_in).astype(jnp.float32),
         "w_gate": (jax.random.normal(ks[1], (e, d, f)) * s_in).astype(cfg.adtype),
         "w_up": (jax.random.normal(ks[2], (e, d, f)) * s_in).astype(cfg.adtype),
         "w_down": (jax.random.normal(ks[3], (e, f, d)) * s_out).astype(cfg.adtype),
@@ -135,8 +136,32 @@ def top_k_gating(probs: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
     return gates, idx
 
 
+def route(probs: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
+    """(gates (T,k), expert ids (T,k)) by the configuration's rule: plain
+    top-k renormalised (top_k_gating), or DeepSeek-V2's group_limited_greedy
+    (the top-k among the experts of the ``topk_group`` groups with the best
+    single prob, the others masked to 0), its gates renormalised only under
+    ``norm_topk_prob`` and multiplied by ``routed_scaling_factor``."""
+    k = cfg.moe_top_k
+    if (cfg.n_group == 1 and cfg.routed_scaling_factor == 1.0
+            and cfg.norm_topk_prob):
+        return top_k_gating(probs, k)
+    if cfg.n_group > 1:
+        t, e = probs.shape
+        grouped = probs.reshape(t, cfg.n_group, e // cfg.n_group)
+        _, gidx = jax.lax.top_k(grouped.max(-1), cfg.topk_group)
+        gmask = jnp.zeros((t, cfg.n_group), bool).at[
+            jnp.arange(t)[:, None], gidx].set(True)
+        probs = jnp.where(jnp.repeat(gmask, e // cfg.n_group, axis=1),
+                          probs, 0.0)
+    gates, idx = jax.lax.top_k(probs, k)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates * cfg.routed_scaling_factor, idx
+
+
 def _capacity(cfg: ModelConfig, num_tokens: int) -> int:
-    c = int(cfg.capacity_factor * cfg.moe_top_k * num_tokens / cfg.num_experts) + 1
+    c = int(cfg.capacity_factor * cfg.moe_top_k * num_tokens / cfg.router_width) + 1
     # MXU-friendly: round capacity up to a multiple of 8 (sublane dim)
     return max(8, -(-c // 8) * 8)
 
@@ -184,13 +209,21 @@ def moe_apply(params: dict, cfg: ModelConfig, x: jax.Array,
               return_stats: bool = False, interpret: bool = False):
     """x: (B, S, d).  Returns (y, aux) where aux carries router losses and,
     when return_stats, per-expert activation counts + per-token expert ids
-    (the signals Gimbal's affinity/EPLB collectors consume).  ``interpret``
+    (the signals Gimbal's affinity/EPLB collectors consume).
+
+    A model that holds a share of the router's experts (cfg.holds_share:
+    experts 0..num_experts-1 of router_width) dispatches only the selections
+    of held experts; the others are neither computed nor counted as
+    capacity drops, so the output is the held and shared experts' part of
+    the layer.  It also returns ``aux["held_rows"]`` (B, S): selections per
+    token that landed on a held expert.  ``interpret``
     runs the "fused" mode's kernels in the Pallas interpreter (CPU).  Under
     "fused", ``params`` may carry stacked expert weights and a ``"layer"``
     index (see _expert_ffn_kernel); the einsum modes take one layer's."""
     b, s, d = x.shape
     t = b * s
     e, k = cfg.num_experts, cfg.moe_top_k
+    share = cfg.holds_share
     xf = x.reshape(t, d)
     if placement is None:
         placement = ExpertPlacement.identity(e)
@@ -205,14 +238,29 @@ def moe_apply(params: dict, cfg: ModelConfig, x: jax.Array,
         # (VMEM count scratch carried across token blocks) — same contract as
         # top_k_gating + dispatch_slots + _dispatch_tables.
         from repro.kernels.topk_router import topk_router_replicated
+        rs, rc = placement.replica_slots, placement.replica_count
+        if share:               # experts held elsewhere map to slot S: none
+            away = cfg.router_width - e
+            rs = jnp.concatenate([rs, jnp.full((away, rs.shape[1]), ns, jnp.int32)])
+            rc = jnp.concatenate([rc, jnp.ones((away,), jnp.int32)])
         gates, expert_ids, slot_idx, pos = topk_router_replicated(
-            logits, k, placement.replica_slots, placement.replica_count, ns,
-            interpret=interpret)
+            logits, k, rs, rc, ns, interpret=interpret, n_group=cfg.n_group,
+            topk_group=cfg.topk_group, scale=cfg.routed_scaling_factor,
+            renorm=cfg.norm_topk_prob)
         keep = pos < cap
+        if share:
+            keep &= slot_idx < ns
     else:
-        gates, expert_ids = top_k_gating(probs, k)                 # (T,k) logical
-        slot_idx = placement.dispatch_slots(expert_ids)            # physical slots
+        gates, expert_ids = route(probs, cfg)                      # (T,k) logical
+        if share:
+            held = expert_ids < e
+            slot_idx = jnp.where(held, placement.dispatch_slots(
+                jnp.minimum(expert_ids, e - 1)), ns)
+        else:
+            slot_idx = placement.dispatch_slots(expert_ids)        # physical slots
         pos, keep = _dispatch_tables(slot_idx, gates, ns, cap)
+        if share:
+            keep &= held
     gates = gates.astype(x.dtype)
 
     if dispatch_mode == "dense":
@@ -255,17 +303,21 @@ def moe_apply(params: dict, cfg: ModelConfig, x: jax.Array,
         y = y + ffn_apply(params["shared"], xf)
 
     # ---- router aux (always fp32) -------------------------------------------
+    ew = cfg.router_width
     me = probs.mean(0)                                             # (E,) mean prob, logical
     # fraction of tokens routed to each LOGICAL expert (pre-placement)
-    ce = jnp.zeros((e,), jnp.float32).at[expert_ids.reshape(-1)].add(1.0) / (t * k)
+    ce = jnp.zeros((ew,), jnp.float32).at[expert_ids.reshape(-1)].add(1.0) / (t * k)
     aux = {
-        "load_balance_loss": e * jnp.sum(me * ce),
+        "load_balance_loss": ew * jnp.sum(me * ce),
         "router_z_loss": jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
     }
+    if share:
+        aux["held_rows"] = (expert_ids < e).sum(-1).astype(jnp.int32).reshape(b, s)
     if return_stats:
         aux["expert_counts"] = jnp.zeros((e,), jnp.int32).at[expert_ids.reshape(-1)].add(1)
         aux["expert_ids"] = expert_ids.reshape(b, s, k)            # logical ids per token
-        aux["dropped_frac"] = 1.0 - keep.mean()
+        aux["dropped_frac"] = (1.0 - keep.sum() / jnp.maximum(
+            (expert_ids < e).sum(), 1)) if share else 1.0 - keep.mean()
     return y.reshape(b, s, d), aux
 
 

@@ -70,6 +70,10 @@ class JaxBackend:
         self.rebalancer = rebalancer
         self.kv_layout = kv_layout
         self.use_kernels = use_kernels
+        # paged MLA latents: the prefill computes the logits of the prompt's
+        # last position only and hands back its held-expert row count
+        self._latent = (kv_layout == "paged"
+                        and model_cfg.attention_type == "mla")
         if kv_layout == "paged":
             self.kv = PagedKVCache(model_cfg, max_slots, max_seq,
                                    block_size=kv_block_size,
@@ -157,6 +161,9 @@ class JaxBackend:
                                    interpret=self.kernel_mode == "interpret")
 
     def _make_prefill(self, plen: int):
+        if self._latent:
+            return self._make_prefill_latent()
+
         @jax.jit
         def fn(params, tokens, placements):
             # the batch=1 cache is born inside the program, on the device
@@ -165,6 +172,26 @@ class JaxBackend:
                              placements=placements,
                              dispatch_mode=self.dispatch_mode,
                              interpret=self.kernel_mode == "interpret")
+        return fn
+
+    def _make_prefill_latent(self):
+        share = self.cfg.holds_share
+
+        @jax.jit
+        def fn(params, tokens, placements, last):
+            """(the logits of position ``last`` (V,), the batch=1 cache,
+            the routed selections of the prompt's tokens that landed on held
+            experts, or None)."""
+            slot_cache = M.init_cache(self.cfg, 1, self.max_seq)
+            logits, cache, aux = M.prefill(
+                params, self.cfg, tokens, slot_cache, placements=placements,
+                dispatch_mode=self.dispatch_mode, head_at=last,
+                interpret=self.kernel_mode == "interpret")
+            held = None
+            if share:
+                real = jnp.arange(tokens.shape[1]) <= last
+                held = jnp.sum(jnp.where(real, aux["held_rows"], 0))
+            return logits[0, 0], cache, held
         return fn
 
     def prefill_cache_info(self):
@@ -179,9 +206,13 @@ class JaxBackend:
         placements = self._placements()
         buckets = sorted({_bucket(min(n, self.max_seq - 1))
                           for n in prompt_lens})
-        jobs = [functools.partial(self._prefill_for_bucket(bl), self.params,
-                                  self._put(np.zeros((1, bl), np.int32)),
-                                  placements) for bl in buckets]
+        jobs = []
+        for bl in buckets:
+            args = [self.params, self._put(np.zeros((1, bl), np.int32)),
+                    placements]
+            if self._latent:
+                args.append(self._put(np.int32(bl - 1)))
+            jobs.append(functools.partial(self._prefill_for_bucket(bl), *args))
         tokens = self._put(self.slot_last_token[:, None])
         pos = self._put(self.kv.positions())
         if self.kv_layout == "paged":
@@ -222,16 +253,27 @@ class JaxBackend:
             padded = np.zeros((1, bl), np.int32)
             padded[0, :plen] = toks
             fn = self._prefill_for_bucket(bl)
-            logits, slot_cache, aux = fn(self.params, self._put(padded),
-                                         self._placements())
-            if self.kv_layout == "paged":
+            aux = {}
+            if self._latent:
+                row, slot_cache, held = fn(self.params, self._put(padded),
+                                           self._placements(),
+                                           self._put(np.int32(plen - 1)))
                 self.kv.write_prefill(slot, slot_cache)
+                first, held = jax.device_get((jnp.argmax(row), held))
+                first = int(first)
+                if held is not None:
+                    sp.note("held_rows", held)
             else:
-                self.kv.cache = write_slot(self.kv.cache, slot_cache, slot,
-                                           self.kv.write_axes)
+                logits, slot_cache, aux = fn(self.params, self._put(padded),
+                                             self._placements())
+                if self.kv_layout == "paged":
+                    self.kv.write_prefill(slot, slot_cache)
+                else:
+                    self.kv.cache = write_slot(self.kv.cache, slot_cache,
+                                               slot, self.kv.write_axes)
+                first = int(jnp.argmax(logits[0, plen - 1]))
             self.slot_req[slot] = r
             self.kv.slot_len[slot] = plen
-            first = int(jnp.argmax(logits[0, plen - 1]))
             self.slot_last_token[slot] = first
             if not (r.kv_migrated and r.first_token_time is not None):
                 r.output_tokens = [first]   # a resumed request keeps its stream
@@ -247,7 +289,8 @@ class JaxBackend:
             sp.note("max_slots", self.max_slots)
             self._sync_placement()
             tokens = self._put(self.slot_last_token[:, None])
-            pos = self._put(self.kv.positions())
+            at = self.kv.positions()
+            pos = self._put(at)
             if self.kv_layout == "paged":
                 for slot, _r in active:
                     self.kv.prepare_append(slot)     # alloc/CoW tail pages
@@ -275,6 +318,11 @@ class JaxBackend:
             stats = None
             if "expert_ids" in aux and rows:
                 stats = np.asarray(aux["expert_ids"])[:, rows]   # (L, B, 1, K)
+                if self.cfg.holds_share:
+                    sp.note("held_rows", (stats < self.cfg.num_experts).sum())
+            if self._latent and trace.on:
+                # resident tokens the step's attention read, new ones included
+                sp.note("latent_tokens", int(at[rows].sum()) + len(rows))
             return eos, stats
 
     def release(self, handle: int, r: Request) -> None:

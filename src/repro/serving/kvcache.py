@@ -129,9 +129,10 @@ class SlotKVCache:
 
 
 class PagedKVCache:
-    """Paged device KV cache for homogeneous GQA attention stacks.
+    """Paged device KV cache for homogeneous GQA attention stacks, and for
+    MLA stacks with a dense prologue (deepseek-v2).
 
-    Layout: per-layer head-major K/V pages of shape (L, P, Hkv, BS, D) where
+    GQA layout: per-layer head-major K/V pages of shape (L, P, Hkv, BS, D) where
     P is the global pool size and BS the block size (head-major so the
     flash-decode kernel's (BS, D) page tile is a legal TPU block; see
     kernels/flash_decode.py).  Physical page 0 is a reserved
@@ -143,18 +144,27 @@ class PagedKVCache:
     re-writing them.  Optional int8 storage keeps a per-(layer, page) scale,
     quantized with training/compression.py::quantize_int8.  The pool lives
     on ``device`` (default: JAX's default device).
+
+    MLA layout: the compressed latents, {"ckv": (L, P, BS, kv_lora_rank),
+    "krope": (L, P, BS, qk_rope_head_dim)}, over all L layers (the dense
+    prologue's included): (R + Dr) x itemsize bytes per token and layer, as
+    ``ModelConfig.kv_bytes_per_token`` counts.  No int8 storage.
     """
 
     def __init__(self, model_cfg: mcfg.ModelConfig, max_slots: int, max_seq: int,
                  *, block_size: int = 16, total_blocks: Optional[int] = None,
                  dtype=None, quantize: bool = False, device=None):
         cfg = model_cfg
-        if (cfg.attention_type != "gqa" or cfg.is_ssm or cfg.is_hybrid
-                or cfg.is_encoder_decoder):
-            raise ValueError("PagedKVCache supports homogeneous GQA stacks only")
-        if cfg.is_moe and (cfg.first_k_dense != 0 or cfg.moe_every != 1):
+        if (cfg.attention_type not in ("gqa", "mla") or cfg.is_ssm
+                or cfg.is_hybrid or cfg.is_encoder_decoder):
+            raise ValueError("PagedKVCache supports GQA and MLA stacks only")
+        self.latent = cfg.attention_type == "mla"
+        if cfg.is_moe and (cfg.moe_every != 1 or (
+                cfg.first_k_dense != 0 and not self.latent)):
             raise ValueError("PagedKVCache requires a homogeneous layer stack "
-                             "(first_k_dense == 0, moe_every == 1)")
+                             "(moe_every == 1; a dense prologue for MLA only)")
+        if self.latent and quantize:
+            raise ValueError("PagedKVCache has no int8 latent pages")
         assert max_slots > 1 and block_size > 0
         self.model_cfg = cfg
         self.max_slots = max_slots
@@ -170,10 +180,18 @@ class PagedKVCache:
         L = cfg.num_layers
         hkv, d = cfg.num_kv_heads, cfg.head_dim
         store = jnp.int8 if quantize else (dtype or cfg.adtype)
-        self.pages: Dict[str, jnp.ndarray] = {
-            "k": jnp.zeros((L, n_pages, hkv, block_size, d), store, device=device),
-            "v": jnp.zeros((L, n_pages, hkv, block_size, d), store, device=device),
-        }
+        if self.latent:
+            widths = {"ckv": cfg.kv_lora_rank, "krope": cfg.qk_rope_head_dim}
+            self.pages: Dict[str, jnp.ndarray] = {
+                n: jnp.zeros((L, n_pages, block_size, w), store, device=device)
+                for n, w in widths.items()}
+        else:
+            self.pages = {
+                "k": jnp.zeros((L, n_pages, hkv, block_size, d), store,
+                               device=device),
+                "v": jnp.zeros((L, n_pages, hkv, block_size, d), store,
+                               device=device),
+            }
         if quantize:
             for name in ("k_scale", "v_scale"):
                 self.pages[name] = jnp.zeros((L, n_pages), jnp.float32,
@@ -274,15 +292,20 @@ class PagedKVCache:
         return q.reshape(blocks.shape), scale.reshape(L, m)
 
     def write_prefill(self, slot: int, slot_cache) -> None:
-        """Scatter a batch=1 prefill cache ({"layers": {"k": (L,1,S,Hkv,D)}})
-        into this slot's non-shared pages.  Shared (prefix-hit) pages were
-        pinned by `alloc` and are NOT re-written — that is the point."""
+        """Scatter a batch=1 prefill cache ({"layers": {"k": (L,1,S,Hkv,D)}},
+        or for MLA {"layers": {"ckv": (L',1,S,R), ...}, "prologue": [{"ckv":
+        (1,S,R), ...}]}) into this slot's non-shared pages.  Shared
+        (prefix-hit) pages were pinned by `alloc` and are NOT re-written —
+        that is the point."""
         bs = self.block_size
         start = int(self._slot_shared[slot])
         n = int(self._slot_nblocks[slot])
         if n == start:
             return
         phys = self.block_tables[slot, start:n].copy()
+        if self.latent:
+            self._write_latent(slot_cache, phys, start, n)
+            return
         for name in ("k", "v"):
             src = slot_cache["layers"][name]                 # (L, 1, S, Hkv, D)
             need = n * bs
@@ -302,6 +325,23 @@ class PagedKVCache:
             else:
                 self.pages[name] = self.pages[name].at[:, phys].set(
                     blocks.astype(self.pages[name].dtype))
+
+    def _write_latent(self, slot_cache, phys, start: int, n: int) -> None:
+        """write_prefill's MLA part: the prologue's latents and the scanned
+        stack's, in layer order, into token-major pages (P, BS, width)."""
+        bs = self.block_size
+        pro = slot_cache.get("prologue", [])
+        for name in ("ckv", "krope"):
+            src = jnp.concatenate([c[name][None] for c in pro]
+                                  + [slot_cache["layers"][name]], axis=0)
+            need = n * bs                                    # (L, 1, S, W)
+            if src.shape[2] < need:
+                src = jnp.pad(src, ((0, 0), (0, 0), (0, need - src.shape[2]),
+                                    (0, 0)))
+            blocks = src[:, 0, start * bs:need].reshape(
+                src.shape[0], n - start, bs, src.shape[3])
+            self.pages[name] = self.pages[name].at[:, phys].set(
+                blocks.astype(self.pages[name].dtype))
 
     def prepare_append(self, slot: int) -> None:
         """Make the page holding position `slot_len` writable before a decode

@@ -1,6 +1,8 @@
 """Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e at
 qwen3-30b-a3b widths (d_model 2048, 32 q / 4 kv heads x 128, 128 experts
-top-8, expert d_ff 768, 16-token KV pages, 4 layers).
+top-8, expert d_ff 768, 16-token KV pages, 4 layers), and of DeepSeek-V2's
+served prefill and paged decode programs at published widths (5 layers, 20
+of 160 routed experts held, 32 rows of 8192-token latent pages).
 
 Nothing runs: each kernel is lowered and compiled by the TPU compiler for a
 described (not attached) v5e chip, so a block that breaks the tiling rules,
@@ -20,7 +22,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.flash_decode import flash_decode, flash_decode_paged
+from repro.configs import get_config
+from repro.kernels.flash_decode import (flash_decode, flash_decode_latent,
+                                        flash_decode_paged)
+from repro.models import model as M
 from repro.kernels.moe_gemm import moe_gemm
 from repro.kernels.topk_router import topk_router_replicated
 from repro.models.moe import ExpertPlacement
@@ -109,3 +114,69 @@ def test_moe_gemm_stacked_compiles(one_chip, capacity, proj):
     _compile(moe_gemm, one_chip, ((E, capacity, d_in), jnp.bfloat16),
              ((LAYERS, E, d_in, d_out), jnp.bfloat16), ((), jnp.int32))
 
+
+
+# DeepSeek-V2 as the benchmark's deepseek-v2-d5-ep8 serves it
+DSV2 = get_config("deepseek-v2-236b").replace(
+    num_layers=5, num_experts=20, n_routed_experts=160,
+    capacity_factor=160 / 6)
+DSV2_SEQ, DSV2_SLOTS = 8192, 32
+DSV2_POOL = DSV2_SLOTS * DSV2_SEQ // PAGE + 1
+
+
+def _dsv2_args(sharding):
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        M.abstract_params(DSV2))
+    placements = jax.ShapeDtypeStruct((4, 20), jnp.int32, sharding=sharding)
+    return params, placements
+
+
+def test_latent_decode_kernel_compiles(one_chip):
+    nb = DSV2_SEQ // PAGE
+    _compile(lambda ql, qr, c, r, ly, bt, ln: flash_decode_latent(
+        ql, qr, c, r, ly, bt, ln, scale=DSV2.attn_softmax_scale), one_chip,
+        ((DSV2_SLOTS, 128, 512), jnp.bfloat16), ((DSV2_SLOTS, 128, 64), jnp.bfloat16),
+        ((5, DSV2_POOL, PAGE, 512), jnp.bfloat16),
+        ((5, DSV2_POOL, PAGE, 64), jnp.bfloat16), ((), jnp.int32),
+        ((DSV2_SLOTS, nb), jnp.int32), ((DSV2_SLOTS,), jnp.int32))
+
+
+def test_deepseek_v2_prefill_compiles(one_chip):
+    """The served prefill (JaxBackend's latent prefill: the logits of the
+    last prompt position, the batch=1 latent cache, held rows) of a
+    4096-token bucket: grouped router and moe_gemm over the held stack."""
+    params, placements = _dsv2_args(one_chip)
+
+    def prefill(params, tokens, placements, last):
+        cache = M.init_cache(DSV2, 1, DSV2_SEQ)
+        logits, cache, aux = M.prefill(params, DSV2, tokens, cache,
+                                       placements=placements,
+                                       dispatch_mode="fused", head_at=last)
+        return logits[0, 0], cache, aux["held_rows"].sum()
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = jax.jit(prefill).lower(params, sds((1, 4096), jnp.int32), placements,
+                                  sds((), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_deepseek_v2_paged_decode_compiles(one_chip):
+    """The served paged decode step: dense prologue, then the MoE scan, the
+    latent pool of every layer carried and read in place by the kernel."""
+    params, placements = _dsv2_args(one_chip)
+    nb = DSV2_SEQ // PAGE
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pages = {"ckv": sds((5, DSV2_POOL, PAGE, 512), jnp.bfloat16),
+             "krope": sds((5, DSV2_POOL, PAGE, 64), jnp.bfloat16)}
+
+    def decode(params, tokens, pages, tables, lengths, placements):
+        return M.decode_step_paged(params, DSV2, tokens, pages, tables, lengths,
+                                   placements=placements, stats=True,
+                                   dispatch_mode="fused", use_kernel=True)
+
+    text = jax.jit(decode).lower(
+        params, sds((DSV2_SLOTS, 1), jnp.int32), pages,
+        sds((DSV2_SLOTS, nb), jnp.int32), sds((DSV2_SLOTS,), jnp.int32),
+        placements).compile().as_text()
+    assert "flash_decode_latent" in text and "moe_gemm" in text
