@@ -37,7 +37,11 @@ The spans and where they open:
                               ``max_slots``; ``held_rows`` as above (of
                               the active rows); ``latent_tokens`` on
                               paged MLA latents: the resident tokens the
-                              step's attention read, new ones included
+                              step's attention read, new ones included;
+                              ``kv_blocks`` on paged GQA pages through
+                              the kernel: the compute blocks of one
+                              layer's ``flash_decode_paged`` call, summed
+                              over the active rows
   ``backend.decode.wait``     the host's read of the decoded tokens
   ``backend.relocate``        ``JaxBackend.apply_placement``
   ``expert.observe``          ``ExpertRebalancer.observe``

@@ -1,19 +1,23 @@
 """Flash-decode: single-token GQA attention with an online softmax over KV
 pages streamed HBM -> VMEM (DESIGN.md §6).
 
-Page layout is head-major, (P, Hkv, BS, D): one grid step reads the (BS, D)
-tile of one kv head of one page.  Those are the array's own last two dims, so
-the block obeys the TPU tiling rule for every page size and head dim (a
-(BS, 1, D) slice of a token-major (P, BS, Hkv, D) page does not).
+Page layout is head-major, (P, Hkv, BS, D): a page holds every KV head's
+(BS, D) tile, contiguous, so one DMA moves a whole page.
 
-Grid (B, Hkv, NB); the NB axis is the sequential ("arbitrary") grid dim, so
-the (m, l, acc) running statistics live in VMEM scratch and are carried
-across pages — the kernel analogue of the shard_map flash-decode combine in
-models/attention.py (which splits the same recurrence across chips).
+Grid (B,): one step per batch row.  The page pools stay in HBM; the step
+walks only the row's resident pages, ceil(length / (ppb * BS)) compute
+blocks in a ``fori_loop``, each block ``ppb`` pages copied into a
+double-buffered VMEM scratch (the next block's copies start before this
+block's compute).  ``ppb`` is derived from the page size (about
+BLOCK_TOKENS tokens a block, within KV_VMEM_BYTES), not a knob.  The
+(m, l, acc) running statistics of the f32 online softmax are carried across
+blocks in VMEM scratch — the kernel analogue of the shard_map flash-decode
+combine in models/attention.py (which splits the same recurrence across
+chips).
 
-GQA-aware: the q block holds all G = Hq/Hkv query heads of one KV head, so
-each KV tile is read exactly once per group (the roofline-optimal layout:
-decode attention is KV-bandwidth-bound).
+GQA-aware: every KV head's G = Hq/Hkv query heads attend the block at once
+(a dot_general batched over Hkv), so each K/V tile is read exactly once
+(decode attention is KV-bandwidth-bound).
 
 ``flash_decode`` (a contiguous (B, S, Hkv, D) cache) is the same kernel over
 that cache cut into private pages with an identity block table.
@@ -34,58 +38,118 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 NEG_INF = -2.0 ** 30
+BLOCK_TOKENS = 128        # tokens a compute block of flash_decode_paged aims at
+KV_VMEM_BYTES = 4 << 20   # cap on its K and V buffers, two slots each
 
 
-def _kernel(bt_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
-            o_ref, m_ref, l_ref, acc_ref,
-            *, block_s: int, softcap: float, quantized: bool):
+def pages_per_block(block_s: int, page_bytes: int, nb: int) -> int:
+    """Pages one compute block of ``flash_decode_paged`` copies: about
+    BLOCK_TOKENS tokens, no more than a row's NB pages, and K + V x 2 slots of
+    ``page_bytes`` pages (all KV heads of one page of K) within
+    KV_VMEM_BYTES."""
+    return max(1, min(BLOCK_TOKENS // block_s,
+                      KV_VMEM_BYTES // (4 * page_bytes), nb))
+
+
+def _kernel(bt_ref, len_ref, ks_ref, vs_ref, q_ref, k_hbm, v_hbm,
+            o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref,
+            *, ppb: int, softcap: float, quantized: bool):
     bi = pl.program_id(0)
-    si = pl.program_id(2)
-    n_s = pl.num_programs(2)
-
-    @pl.when(si == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
+    _, hkv, _, bs, d = k_buf.shape
+    nb = bt_ref.shape[1]
+    tb = ppb * bs                                   # tokens a block
     length = len_ref[bi]
+    n_pages = jnp.minimum((length + bs - 1) // bs, nb)
+    n_blocks = (n_pages + ppb - 1) // ppb
 
-    # Skip fully-masked pages entirely: no wasted flops past `length`, and a
-    # length-0 row leaves l at 0 so the output is exactly zero (with a finite
-    # NEG_INF mask an unguarded page would contribute exp(0)=1 everywhere and
-    # emit mean(v) instead).
-    @pl.when(si * block_s < length)
-    def _update():
-        q = q_ref[0, 0].astype(jnp.float32)         # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)         # (BS, D)
-        v = v_ref[0, 0].astype(jnp.float32)
+    def copies(blk, slot):
+        """The DMAs of block ``blk`` into buffer ``slot``, one per resident
+        page of K and of V: pages past the row's last are not copied."""
+        out = []
+        for p in range(ppb):
+            page = blk * ppb + p
+            phys = bt_ref[bi, jnp.minimum(page, nb - 1)]
+            out.append((page < n_pages, [
+                pltpu.make_async_copy(src.at[phys], buf.at[slot, :, p],
+                                      sems.at[i, slot])
+                for i, (src, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf)))]))
+        return out
+
+    def start(blk, slot):
+        for resident, cps in copies(blk, slot):
+            @pl.when(resident)
+            def _():
+                for c in cps:
+                    c.start()
+
+    def wait(blk, slot):
+        for resident, cps in copies(blk, slot):
+            @pl.when(resident)
+            def _():
+                for c in cps:
+                    c.wait()
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[0].astype(jnp.float32)                             # (Hkv, G, D)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        start(0, 0)
+
+    def body(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _next():
+            start(blk + 1, 1 - slot)
+
+        wait(blk, slot)
+        k = k_buf[slot].astype(jnp.float32).reshape(hkv, tb, d)  # (Hkv, T, D)
+        v = v_buf[slot].astype(jnp.float32).reshape(hkv, tb, d)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32)
+        s = s * (d ** -0.5)                                      # (Hkv, G, T)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tb), 2)
+        valid = blk * tb + col < length
         if quantized:
-            blk = bt_ref[bi, si]                    # physical page id
-            k = k * ks_ref[blk]
-            v = v * vs_ref[blk]
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G, BS)
-        s = s * (q.shape[-1] ** -0.5)
+            # per-page scales act on the scores and the probabilities: the
+            # same products as dequantizing K and V, on (Hkv, G, T) only
+            def per_col(sc_ref):
+                row = jnp.zeros((1, 1, tb), jnp.float32)
+                for p in range(ppb):
+                    phys = bt_ref[bi, jnp.minimum(blk * ppb + p, nb - 1)]
+                    row = jnp.where(col // bs == p, sc_ref[phys], row)
+                return row
+            s = s * per_col(ks_ref)
         if softcap > 0:
             s = jnp.tanh(s / softcap) * softcap
-        jpos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(jpos < length, s, NEG_INF)
+        s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_ref[...]                          # (G, 1)
+        m_prev = m_ref[...]                                      # (Hkv, G, 1)
         m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        p = jnp.exp(s - m_new)                       # (G, BS)
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        # rows past the length hold stale or unwritten VMEM and a page past
+        # it may have any scale: 0 * NaN is NaN, so they are zeroed, not
+        # only given zero weight
+        if quantized:
+            p = jnp.where(valid, p * per_col(vs_ref), 0.0)
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, tb, 1), 1)
+        v = jnp.where(blk * tb + row < length, v, 0.0)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
+        return carry
 
-    @pl.when(si == n_s - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
-                       ).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_blocks, body, 0)
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
+                ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
@@ -101,50 +165,56 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     valid tokens per row.  Optional per-page int8 scales (P,) f32 dequantize
     pages in-kernel.  Returns (B, Hq, D).
 
-    The block table, lengths and scales ride in as scalar-prefetch operands
-    (pltpu.PrefetchScalarGridSpec, SMEM), so the k/v BlockSpec index maps
-    select the PHYSICAL page for grid step (b, h, si) — the standard TPU
-    paged-attention trick: the DMA engine chases the indirection, not the
-    compute loop.  Fully-masked pages are skipped (pl.when on
-    `si*BS < length`).  ``interpret`` runs the kernel in the Pallas
-    interpreter (CPU); it has no default other than compiled."""
+    Grid (B,): one step per row.  The pools stay in HBM (``pl.ANY``); the
+    step walks ceil(length / (ppb * BS)) compute blocks in a ``fori_loop``,
+    each ``ppb`` (``pages_per_block``) whole (Hkv, BS, D) pages of K and of V
+    copied by DMA into a double-buffered VMEM scratch, the next block's
+    copies started before this block's compute.  The block table, lengths
+    and scales ride in as scalar-prefetch operands (SMEM), so the DMAs chase
+    the physical page ids.  Every KV head's G query heads attend the block
+    at once (a dot_general batched over Hkv), with an f32 online softmax
+    across blocks; a length-0 row runs no block and its output is exactly
+    zero.  ``interpret`` runs the kernel in the Pallas interpreter (CPU); it
+    has no default other than compiled."""
     b, hq, d = q.shape
     _, hkv, bs, _ = k_pages.shape
     nb = block_tables.shape[1]
     g = hq // hkv
+    ppb = pages_per_block(bs, hkv * bs * d * k_pages.dtype.itemsize, nb)
     qg = q.reshape(b, hkv, g, d)
     quantized = k_scale is not None
     ks = k_scale if quantized else jnp.zeros((1,), jnp.float32)
     vs = v_scale if quantized else jnp.zeros((1,), jnp.float32)
 
-    def q_map(bi, hi, si, bt, ln, ks_, vs_):
-        return (bi, hi, 0, 0)
-
-    def kv_map(bi, hi, si, bt, ln, ks_, vs_):
-        return (bt[bi, si], hi, 0, 0)
+    def row_map(bi, bt, ln, ks_, vs_):
+        return (bi, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, hkv, nb),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), q_map),
-            pl.BlockSpec((1, 1, bs, d), kv_map),
-            pl.BlockSpec((1, 1, bs, d), kv_map),
+            pl.BlockSpec((1, hkv, g, d), row_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), q_map),
+        out_specs=pl.BlockSpec((1, hkv, g, d), row_map),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),         # m
-            pltpu.VMEM((g, 1), jnp.float32),         # l
-            pltpu.VMEM((g, d), jnp.float32),         # acc
+            pltpu.VMEM((2, hkv, ppb, bs, d), k_pages.dtype),   # K blocks
+            pltpu.VMEM((2, hkv, ppb, bs, d), v_pages.dtype),   # V blocks
+            pltpu.SemaphoreType.DMA((2, 2)),                   # (K|V, slot)
+            pltpu.VMEM((hkv, g, 1), jnp.float32),              # m
+            pltpu.VMEM((hkv, g, 1), jnp.float32),              # l
+            pltpu.VMEM((hkv, g, d), jnp.float32),              # acc
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, block_s=bs, softcap=softcap,
+        functools.partial(_kernel, ppb=ppb, softcap=softcap,
                           quantized=quantized),
         grid_spec=grid_spec,
+        name="flash_decode_paged",
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), ks, vs,
       qg, k_pages, v_pages)

@@ -22,6 +22,7 @@ import numpy as np
 from repro.core import trace
 from repro.core.eplb import ExpertRebalancer
 from repro.core.types import Request
+from repro.kernels.flash_decode import pages_per_block
 from repro.models import config as mcfg
 from repro.models import model as M
 from repro.serving.kvcache import PagedKVCache, SlotKVCache, write_slot
@@ -323,6 +324,12 @@ class JaxBackend:
             if self._latent and trace.on:
                 # resident tokens the step's attention read, new ones included
                 sp.note("latent_tokens", int(at[rows].sum()) + len(rows))
+            elif self.kv_layout == "paged" and self.use_kernels and trace.on:
+                # flash_decode_paged's compute blocks in one layer's call
+                _, _, hkv, bs, d = self.kv.pages["k"].shape
+                page_bytes = hkv * bs * d * self.kv.pages["k"].dtype.itemsize
+                tb = bs * pages_per_block(bs, page_bytes, self.kv.max_blocks)
+                sp.note("kv_blocks", int((-(-(at[rows] + 1) // tb)).sum()))
             return eos, stats
 
     def release(self, handle: int, r: Request) -> None:

@@ -194,15 +194,26 @@ def _paged_case(seed, b, hq, hkv, d, bs, nb, dtype=jnp.float32):
 
 @pytest.mark.parametrize("b,hq,hkv,d,bs,nb", [(4, 4, 2, 16, 16, 4),
                                               (2, 8, 8, 32, 32, 3),
-                                              (3, 4, 1, 64, 16, 2)])
+                                              (3, 4, 1, 64, 16, 2),
+                                              # granite's and qwen3's heads
+                                              # (Hkv 8 / G 4, Hkv 4 / G 8);
+                                              # NB 11 is not a multiple of
+                                              # the 8 pages a block
+                                              (6, 32, 8, 16, 16, 11),
+                                              (6, 32, 4, 16, 16, 11),
+                                              (5, 32, 4, 32, 16, 16)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_decode_paged_matches_ref(b, hq, hkv, d, bs, nb, dtype):
     """Ragged lengths, zero-length rows and the exactly-full case in one
-    sweep: lengths cover {0, mid-block, block boundary, nb*bs}."""
-    from repro.kernels.flash_decode import flash_decode_paged
+    sweep: lengths cover {0, mid-page, page boundary, compute-block
+    boundary (ppb * bs, where b > 4), nb*bs}."""
+    from repro.kernels.flash_decode import flash_decode_paged, pages_per_block
     q, kp, vp, bt = _paged_case(b * 31 + nb, b, hq, hkv, d, bs, nb, dtype)
+    ppb = pages_per_block(bs, hkv * bs * d * jnp.dtype(dtype).itemsize, nb)
     lens = np.linspace(0, nb * bs, b).astype(np.int32)
-    lens[b // 2] = bs                                     # a block boundary
+    lens[b // 2] = bs                                     # a page boundary
+    if b > 4:
+        lens[1] = ppb * bs                                # a block boundary
     lengths = jnp.asarray(lens)
     out = flash_decode_paged(q, kp, vp, bt, lengths, interpret=True)
     want = ref.ref_flash_decode_paged(q, kp, vp, bt, lengths)
@@ -210,6 +221,49 @@ def test_flash_decode_paged_matches_ref(b, hq, hkv, d, bs, nb, dtype):
                                np.asarray(want, np.float32), **TOL[dtype])
     # a zero-length row attends to nothing and must be exactly zero
     assert (np.asarray(out, np.float32)[np.asarray(lengths) == 0] == 0).all()
+
+
+@pytest.mark.parametrize("store", ["float32", "int8"])
+def test_flash_decode_paged_ignores_pages_past_length(store):
+    """Every page past each row's length, the tail of each row's last page
+    and the reserved page 0 hold NaN (int8: NaN scales), and one row's
+    table points its tail at page 0: the output is finite and equals the
+    reference on the same pool without the NaN, so no masked page or row
+    reaches the result."""
+    from repro.kernels.flash_decode import flash_decode_paged
+    b, hq, hkv, d, bs, nb = 4, 32, 8, 16, 16, 11
+    q, kp, vp, bt = _paged_case(23, b, hq, hkv, d, bs, nb)
+    lens = np.asarray([37, 0, 128, 150], np.int32)
+    tables = np.array(bt)
+    tables[0, 3:] = 0                    # a free tail, as PagedKVCache keeps
+    bt = jnp.asarray(tables)
+    kw, kw_clean = {}, {}
+    if store == "int8":
+        kp, ksc = _int8_pages(kp)
+        vp, vsc = _int8_pages(vp)
+        kw_clean = dict(k_scale=ksc, v_scale=vsc)
+    dead = np.zeros(kp.shape[:1] + (bs,), bool)      # (P, BS) unread rows
+    dead[0] = True
+    for r in range(b):
+        used = -(-lens[r] // bs)
+        dead[tables[r, used:]] = True
+        if lens[r] % bs:
+            dead[tables[r, used - 1], lens[r] % bs:] = True
+    if store == "int8":
+        nan_pages = dead.all(-1)
+        kw = dict(k_scale=jnp.where(nan_pages, jnp.nan, ksc),
+                  v_scale=jnp.where(nan_pages, jnp.nan, vsc))
+        kn, vn = kp, vp
+    else:
+        mask = jnp.asarray(dead)[:, None, :, None]
+        kn, vn = jnp.where(mask, jnp.nan, kp), jnp.where(mask, jnp.nan, vp)
+    lengths = jnp.asarray(lens)
+    out = np.asarray(flash_decode_paged(q, kn, vn, bt, lengths, **kw,
+                                        interpret=True))
+    assert np.isfinite(out).all()
+    want = ref.ref_flash_decode_paged(q, kp, vp, bt, lengths, **kw_clean)
+    np.testing.assert_allclose(out, np.asarray(want), rtol=2e-4, atol=2e-4)
+    assert (out[1] == 0).all()
 
 
 def test_flash_decode_paged_single_block_pages():

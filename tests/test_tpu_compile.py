@@ -67,15 +67,20 @@ def _compile(fn, sharding, *shapes):
 
 @pytest.mark.parametrize("store", ["bfloat16", "int8"])
 def test_flash_decode_paged_compiles(one_chip, store):
-    pages = ((POOL, HKV, PAGE, HEAD), jnp.dtype(store))
-    shapes = [((SLOTS, HQ, HEAD), jnp.bfloat16), pages, pages,
-              ((SLOTS, NB), jnp.int32), ((SLOTS,), jnp.int32)]
-    if store == "int8":
-        shapes += [((POOL,), jnp.float32)] * 2
-        _compile(lambda q, k, v, bt, ln, ks, vs: flash_decode_paged(
-            q, k, v, bt, ln, k_scale=ks, v_scale=vs), one_chip, *shapes)
-    else:
-        _compile(flash_decode_paged, one_chip, *shapes)
+    """At both GQA cells' serving shapes: 32 slots of 2048 tokens in
+    16-token pages, 32 query heads over 4 (qwen3) or 8 (granite) KV heads."""
+    slots = 32
+    pool = slots * NB + 1
+    for hkv in (4, 8):
+        pages = ((pool, hkv, PAGE, HEAD), jnp.dtype(store))
+        shapes = [((slots, HQ, HEAD), jnp.bfloat16), pages, pages,
+                  ((slots, NB), jnp.int32), ((slots,), jnp.int32)]
+        if store == "int8":
+            shapes += [((pool,), jnp.float32)] * 2
+            _compile(lambda q, k, v, bt, ln, ks, vs: flash_decode_paged(
+                q, k, v, bt, ln, k_scale=ks, v_scale=vs), one_chip, *shapes)
+        else:
+            _compile(flash_decode_paged, one_chip, *shapes)
 
 
 def test_flash_decode_contiguous_compiles(one_chip):

@@ -188,6 +188,24 @@ def test_pad_counter_is_bucket_minus_prompt_length(cluster):
 
 
 @pytest.mark.slow
+def test_decode_counts_the_paged_kernels_compute_blocks():
+    """``kv_blocks`` on each ``backend.decode`` record: the compute blocks
+    of one layer's flash_decode_paged call, ceil((resident + 1) / (ppb *
+    BS)) summed over the active rows; here 8 pages of 16 a block over a
+    16-page table, and one row crosses a block boundary between steps."""
+    from repro.serving.backend import JaxBackend
+    cfg = get_smoke_config("granite-3-8b")
+    be = JaxBackend(cfg, serve.init_params(cfg), max_slots=3, max_seq=256,
+                    kv_layout="paged", kv_block_size=16, use_kernels=True)
+    reqs = _requests([127, 9], out=3)
+    trace.enable()
+    active = [(be.start(r, 0.0)[0], r) for r in reqs]
+    for _ in range(2):
+        be.decode(active, 0.0)
+    dec = _named(trace.drain(), "backend.decode")
+    assert [r.attrs["kv_blocks"] for r in dec] == [1 + 1, 2 + 1]
+
+
 def test_recorder_off_records_nothing_and_serves_the_same_tokens():
     cfg = get_smoke_config("qwen3-30b-a3b")
     outs = []
